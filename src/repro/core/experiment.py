@@ -70,6 +70,7 @@ from typing import Iterator, List, Optional, Sequence, Union
 import jax
 import numpy as np
 
+from .. import telemetry
 from .histogram import HistogramConfig
 from .policy import (FixedKeepAlivePolicy, HybridConfig, HybridHistogramPolicy,
                      NoUnloadingPolicy, Policy, SpesConfig, SpesPolicy)
@@ -381,6 +382,12 @@ def as_trace(obj) -> Trace:
 
 def _sweep_one(trace: Trace, specs: Sequence, eng: str,
                opts: EngineOptions) -> SweepResult:
+    with telemetry.span("sweep"):
+        return _sweep_families(trace, specs, eng, opts)
+
+
+def _sweep_families(trace: Trace, specs: Sequence, eng: str,
+                    opts: EngineOptions) -> SweepResult:
     n = trace.n_apps
     S = len(specs)
     cold = np.zeros((S, n), np.int64)
@@ -396,10 +403,11 @@ def _sweep_one(trace: Trace, specs: Sequence, eng: str,
                        final_prewarm=out.final_prewarm,
                        final_keep_alive=out.final_keep_alive,
                        invocations=out.invocations)
-        cold[rows] = out["cold"]
-        waste[rows] = out["wasted_minutes"]
-        pre[rows] = out["final_prewarm"]
-        keep[rows] = out["final_keep_alive"]
+        with telemetry.span("sweep.fill"):
+            cold[rows] = out["cold"]
+            waste[rows] = out["wasted_minutes"]
+            pre[rows] = out["final_prewarm"]
+            keep[rows] = out["final_keep_alive"]
         inv = out["invocations"]
 
     if eng == "scalar":
@@ -417,7 +425,8 @@ def _sweep_one(trace: Trace, specs: Sequence, eng: str,
 
     # The trace is padded ONCE for every family and config (list-backed
     # traces rebuild the padded arrays on each to_padded call).
-    padded = trace.to_padded()
+    with telemetry.span("sweep.pad"):
+        padded = trace.to_padded()
     if window_idx:
         # No histogram state in this family — the float64 fused sweep is
         # already oracle-exact, so "pallas"/"reference" alias it.

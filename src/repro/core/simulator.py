@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from . import policy_math
 from .histogram import HistogramConfig
 from .policy import (FixedKeepAlivePolicy, HybridConfig, HybridHistogramPolicy,
@@ -572,7 +573,8 @@ def _hybrid_sweep_scan(times, blk: policy_math.HybridSweepBlock,
     step = lambda carry, t: (
         policy_math.fused_hybrid_sweep_step_math(
             t, *carry, blk=blk, ids=ids), None)
-    carry, _ = jax.lax.scan(step, init, times.T)
+    with jax.named_scope("sweep.scan"):
+        carry, _ = jax.lax.scan(step, init, times.T)
     (last_t, gcum, goob, gcv_sum, gcv_sum_sq, prewarm, unload_at,
      cold, waste) = carry
     oob_seen = goob > 0 if ids.t else (goob > 0)[blk.t_group]
@@ -600,7 +602,7 @@ def _hybrid_sweep_scan_pallas(gaps, cfg_i32, cfg_f32, n_bins: int,
     # no events.
     it_lo, it_hi, code = gaps
     n_real = code.shape[0]
-    pad = (-n_real) % min(tile_apps, n_real) if n_real else 0
+    pad = _tile_pad(n_real, tile_apps)
     if pad:
         rows = lambda x, fill: jnp.concatenate(
             [x, jnp.full((pad, x.shape[1]), fill, x.dtype)])
@@ -626,7 +628,8 @@ def _hybrid_sweep_scan_pallas(gaps, cfg_i32, cfg_f32, n_bins: int,
         return out, None
 
     columns = tuple(x.T[:, None, :] for x in (it_lo, it_hi, code))
-    carry, _ = jax.lax.scan(step, init, columns)
+    with jax.named_scope("sweep.scan"):
+        carry, _ = jax.lax.scan(step, init, columns)
     carry = tuple(c[..., :n_real] for c in carry)
     (cum, oob, _, _, prewarm, unload_at, cold, waste) = carry
     return cold[:, 0], waste[:, 0], oob[:, 0] > 0, prewarm[:, 0], \
@@ -875,6 +878,11 @@ def _run_hybrid_sweep(trace: Trace, hybrids: Sequence[HybridConfig],
         x = scaleout.pad_app_rows(x, ndev)
         return jax.device_put(x, scaleout.app_sharding(mesh, x.ndim))
 
+    def take(work):
+        # the bucket's fancy-index copy runs inside the generator
+        with telemetry.span("sweep.bucket"):
+            return next(work, None)
+
     def run_all():
         # Streaming with a one-chunk lookahead: at most two chunk copies are
         # alive at once (the one scanning and the one whose host->device
@@ -884,36 +892,48 @@ def _run_hybrid_sweep(trace: Trace, hybrids: Sequence[HybridConfig],
         def prep(sel_sub):
             sel, sub = sel_sub
             if not use_pallas:
-                cur = put(sub)
-                return sel, {key: cur for _, _, key in bands}, None
+                with telemetry.span("sweep.put"):
+                    cur = put(sub)
+                return sel, sub.shape[1], {key: cur for _, _, key in bands}, \
+                    None
             inputs, last = {}, None
             for _, _, key in bands:
                 if key not in inputs:
-                    gaps, last = _gap_columns(sub, key[1], key[0])
-                    inputs[key] = _put_gaps(gaps, mesh)
-            return sel, inputs, last
+                    with telemetry.span("sweep.gap_columns"):
+                        gaps, last = _gap_columns(sub, key[1], key[0])
+                    with telemetry.span("sweep.put"):
+                        inputs[key] = _put_gaps(gaps, mesh)
+            return sel, sub.shape[1], inputs, last
 
         work = _chunked_buckets(times, counts, chunk)
-        pending = next(work, None)
+        pending = take(work)
         if pending is None:
             return
         pending = prep(pending)
         while pending is not None:
-            sel, inputs, last = pending
-            nxt = next(work, None)
+            sel, width, inputs, last = pending
+            nxt = take(work)
             pending = None if nxt is None else prep(nxt)
+            n_events = int(counts[sel].sum())
+            rows = _scan_rows(len(sel), ndev, tile_apps if use_pallas else 0)
             for idx, fn, key in bands:
+                telemetry.count("sweep.event_steps", len(idx) * n_events)
+                telemetry.count("sweep.app_steps", len(idx) * rows * width)
+                with telemetry.span("sweep.dispatch"):
+                    res = fn(inputs[key])
                 # [..., :len(sel)] drops the masked mesh-padding rows (a
                 # no-op on the unsharded path).
-                out = [np.asarray(o)[..., :len(sel)] for o in fn(inputs[key])]
-                if use_pallas:      # the kernel carries no clock
-                    out.insert(3, last)
-                c, w, oobh, last_t, pw, ub = out
-                at = np.ix_(idx, sel)
-                cold[at] = c
-                oob_flags[at] = oobh
-                waste[at], pre[at], keep[at] = _absolute_results(
-                    w, last_t, pw, ub, 0.0, duration, include_trailing)
+                with telemetry.span("sweep.pull"):
+                    out = [np.asarray(o)[..., :len(sel)] for o in res]
+                with telemetry.span("sweep.scatter"):
+                    if use_pallas:      # the kernel carries no clock
+                        out.insert(3, last)
+                    c, w, oobh, last_t, pw, ub = out
+                    at = np.ix_(idx, sel)
+                    cold[at] = c
+                    oob_flags[at] = oobh
+                    waste[at], pre[at], keep[at] = _absolute_results(
+                        w, last_t, pw, ub, 0.0, duration, include_trailing)
 
     if use_pallas:
         run_all()
@@ -934,8 +954,9 @@ def _run_hybrid_sweep(trace: Trace, hybrids: Sequence[HybridConfig],
         if h.use_arima and oob_flags[s].any():
             from ..forecast.replay import replay_oob_apps
             aidx = np.where(oob_flags[s])[0]
-            out = replay_oob_apps(times, counts, duration, h, aidx,
-                                  include_trailing)
+            with telemetry.span("sweep.arima_replay"):
+                out = replay_oob_apps(times, counts, duration, h, aidx,
+                                      include_trailing)
             cold[s, aidx] = out["cold"]
             waste[s, aidx] = out["wasted_minutes"]
             pre[s, aidx] = out["final_prewarm"]
@@ -943,6 +964,23 @@ def _run_hybrid_sweep(trace: Trace, hybrids: Sequence[HybridConfig],
     return dict(cold=cold, invocations=counts.astype(np.int64),
                 wasted_minutes=waste, final_prewarm=pre,
                 final_keep_alive=keep)
+
+
+def _scan_rows(n: int, ndev: int, tile_apps: int) -> int:
+    """Rows a scan steps through for an ``n``-app chunk: the mesh pads
+    the rows to a multiple of its ``ndev`` devices, then the Pallas scan
+    pads each device's rows to its kernel tile as
+    :func:`_hybrid_sweep_scan_pallas` does (``tile_apps`` 0: no tile)."""
+    per = -(-n // ndev)
+    if tile_apps:
+        per += _tile_pad(per, tile_apps)
+    return per * ndev
+
+
+def _tile_pad(n: int, tile_apps: int) -> int:
+    """Rows the Pallas scan adds to ``n`` app rows to fill its kernel
+    tiles (a chunk below one tile is its own tile)."""
+    return (-n) % min(tile_apps, n) if n else 0
 
 
 # -- pre-sweep batched engine (benchmark/regression baseline) ----------------

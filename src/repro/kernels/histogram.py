@@ -259,6 +259,7 @@ def fused_hybrid_sweep_step_pallas(it_lo, it_hi, code, cum, oob, cv_sum,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="hybrid_sweep_step",
     )(cfg_i32, cfg_f32, it_lo, it_hi, code, *state)
     if pad:
         outs = tuple(o[..., :-pad] for o in outs)
