@@ -69,6 +69,9 @@ deprecation cycle (they raise an ``AttributeError`` pointing at
 from __future__ import annotations
 
 import dataclasses
+import functools
+import os
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from typing import Optional, Sequence
 
@@ -94,6 +97,12 @@ BUCKET_EDGES = (64, 512, 4096, 1 << 62)
 # by the config-axis length so total device state stays bounded too.
 DEFAULT_APP_CHUNK = 131072
 _MIN_AUTO_CHUNK = 4096
+
+# Elements of one row block of the host gap columns (``_gap_columns``):
+# large enough that the Python part of a block's numpy calls, which holds
+# the GIL, is small beside their work on the pool's threads, and small
+# enough to keep a block's float64 temporaries to a few MiB.
+_GAP_BLOCK = 1 << 19
 
 
 @dataclasses.dataclass
@@ -384,19 +393,26 @@ def _run_spes_sweep(trace: Trace, cfgs, include_trailing: bool = True, *,
                 final_keep_alive=keep)
 
 
-def _buckets(times: np.ndarray, counts: np.ndarray):
-    """Yield (app_index_array, trimmed_times) grouped by event count."""
+def _bucket_rows(counts: np.ndarray):
+    """Yield (app_index_array, width) grouped by event count: the apps of
+    each bucket and the most events any of them has."""
     lo = 0
     for edge in BUCKET_EDGES:
         sel = np.where((counts > lo) & (counts <= edge))[0]
         if len(sel):
-            width = int(counts[sel].max())
-            yield sel, times[sel][:, :width]
+            yield sel, int(counts[sel].max())
         lo = edge
 
 
-def _chunked_buckets(times: np.ndarray, counts: np.ndarray, app_chunk: int):
-    """Bucket by event count, then chunk each bucket over apps.
+def _buckets(times: np.ndarray, counts: np.ndarray):
+    """Yield (app_index_array, trimmed_times) grouped by event count."""
+    for sel, width in _bucket_rows(counts):
+        yield sel, times[sel, :width]
+
+
+def _chunked_rows(counts: np.ndarray, app_chunk: int):
+    """Bucket by event count, then chunk each bucket over apps: yield
+    (app_index_array, width) for each chunk.
 
     The last chunk of a bucket is ragged when the bucket size is not a
     multiple of ``app_chunk`` — every consumer below handles that, but an
@@ -406,10 +422,17 @@ def _chunked_buckets(times: np.ndarray, counts: np.ndarray, app_chunk: int):
     if app_chunk < 1:
         raise ValueError(
             f"app_chunk must be a positive app count, got {app_chunk}")
-    for sel, sub in _buckets(times, counts):
-        _check_scan_width(sub.shape[1])
+    for sel, width in _bucket_rows(counts):
+        _check_scan_width(width)
         for lo in range(0, len(sel), app_chunk):
-            yield sel[lo:lo + app_chunk], sub[lo:lo + app_chunk]
+            yield sel[lo:lo + app_chunk], width
+
+
+def _chunked_buckets(times: np.ndarray, counts: np.ndarray, app_chunk: int):
+    """:func:`_chunked_rows` with each chunk's trimmed times:
+    (app_index_array, times[app_index_array, :width])."""
+    for sel, width in _chunked_rows(counts, app_chunk):
+        yield sel, times[sel, :width]
 
 
 def _check_scan_width(width: int) -> None:
@@ -684,35 +707,78 @@ def _rebase_chunk(sub: np.ndarray):
     return sub.astype(np.float64) - t0[:, None], t0
 
 
-def _gap_columns(sub: np.ndarray, bin_minutes: float, n_bins: int):
+@functools.cache
+def _gap_pool() -> ThreadPoolExecutor:
+    """The host threads that build gap columns, one per usable core. Made
+    on first use and kept for the life of the process."""
+    return ThreadPoolExecutor(len(os.sched_getaffinity(0)),
+                              thread_name_prefix="gap_columns")
+
+
+def _gap_columns(times: np.ndarray, bin_minutes: float, n_bins: int,
+                 rows: Optional[np.ndarray] = None,
+                 width: Optional[int] = None):
     """The Pallas kernel's event columns for one chunk of times (host).
 
-    Each event's idle time is differenced in float64 from the trace's own
-    clock, exactly as the scalar oracle does, and classified there
-    (``policy_math.encode_gaps``). The kernel gets the codes and the idle
-    time as a float32 bracket ``it_lo <= it <= it_hi`` (rounded down and
-    up), which keeps its warm verdicts exact against float32 window
+    The chunk is ``times[rows, :width]`` (every row and column where
+    ``None``), read in place block by block: no copy of the chunk is
+    made. Each event's idle time is differenced in float64 from the
+    trace's own clock, exactly as the scalar oracle does, and classified
+    there (``policy_math.encode_gaps``). The kernel gets the codes and the
+    idle time as a float32 bracket ``it_lo <= it <= it_hi`` (rounded down
+    and up), which keeps its warm verdicts exact against float32 window
     bounds (``policy_math.warm_from_bounds``) and its waste within one
     float32 ulp of each gap. Returns ``(it_lo, it_hi, code)``, each
     [n, width], and each app's last event time (-inf without events).
+
+    Every element's arithmetic is independent of its block, so the blocks
+    (about ``_GAP_BLOCK`` elements each) are spread over
+    :func:`_gap_pool` whenever the chunk holds more than two of them.
     """
-    clock = np.asarray(sub, np.float64)
-    valid = np.isfinite(clock)
-    first = np.zeros_like(valid)
-    first[:, 0] = valid[:, 0]
-    gap = np.zeros_like(clock)
-    gap[:, 1:] = np.where(valid[:, 1:], clock[:, 1:] - np.where(
-        valid[:, 1:], clock[:, :-1], 0.0), 0.0)
-    code = policy_math.encode_gaps(gap, valid, first, bin_minutes, n_bins)
-    it_lo = gap.astype(np.float32)
-    it_hi = it_lo.copy()
-    down = it_lo > gap
-    it_lo[down] = np.nextafter(it_lo[down], np.float32(-np.inf))
-    up = it_hi < gap
-    it_hi[up] = np.nextafter(it_hi[up], np.float32(np.inf))
-    n_ev = valid.sum(axis=1)
-    last = np.where(n_ev > 0, clock[np.arange(len(clock)),
-                                    np.maximum(n_ev - 1, 0)], -np.inf)
+    n = len(times) if rows is None else len(rows)
+    width = times.shape[1] if width is None else int(width)
+    it_lo = np.empty((n, width), np.float32)
+    it_hi = np.empty((n, width), np.float32)
+    code = np.empty((n, width), np.int32)
+    last = np.empty(n, np.float64)
+    step = max(_GAP_BLOCK // max(width, 1), 1)
+
+    def block(r0):
+        r1 = min(r0 + step, n)
+        src = slice(r0, r1) if rows is None else rows[r0:r1]
+        clock = np.asarray(times[src, :width], np.float64)
+        valid = np.isfinite(clock)
+        first = np.zeros_like(valid)
+        first[:, 0] = valid[:, 0]
+        # +inf padding follows the last event: the subtrahend is replaced
+        # there, so no inf - inf is formed (any thread may run this block,
+        # and a caller's errstate does not reach it).
+        gap = np.zeros_like(clock)
+        np.subtract(clock[:, 1:], np.where(valid[:, 1:], clock[:, :-1], 0.0),
+                    out=gap[:, 1:], where=valid[:, 1:])
+        code[r0:r1] = policy_math.encode_gaps(gap, valid, first, bin_minutes,
+                                              n_bins)
+        lo, hi = it_lo[r0:r1], it_hi[r0:r1]
+        lo[...] = gap
+        hi[...] = lo
+        # Rows are sorted, so gap >= 0: a float32 above it is positive and
+        # one below it is not negative, and its neighbour toward the gap
+        # (``np.nextafter``) is one step of its bit pattern away.
+        np.subtract(lo.view(np.int32), 1, out=lo.view(np.int32),
+                    where=lo > gap)
+        np.add(hi.view(np.int32), 1, out=hi.view(np.int32), where=hi < gap)
+        n_ev = valid.sum(axis=1)
+        last[r0:r1] = np.where(
+            n_ev > 0, clock[np.arange(r1 - r0), np.maximum(n_ev - 1, 0)],
+            -np.inf)
+
+    starts = range(0, n, step)
+    if len(starts) > 2:
+        for _ in _gap_pool().map(block, starts):
+            pass
+    else:
+        for r0 in starts:
+            block(r0)
     return (it_lo, it_hi, code), last
 
 
@@ -879,7 +945,8 @@ def _run_hybrid_sweep(trace: Trace, hybrids: Sequence[HybridConfig],
         return jax.device_put(x, scaleout.app_sharding(mesh, x.ndim))
 
     def take(work):
-        # the bucket's fancy-index copy runs inside the generator
+        # the jnp path's row copy runs inside the generator; the Pallas
+        # path's generator only picks the rows (``_gap_columns`` reads them)
         with telemetry.span("sweep.bucket"):
             return next(work, None)
 
@@ -889,23 +956,26 @@ def _run_hybrid_sweep(trace: Trace, hybrids: Sequence[HybridConfig],
         # transfer is enqueued ahead of blocking on the current result).
         # With a mesh, the row-sharded device_put enqueues one transfer PER
         # DEVICE, so the lookahead double-buffers per device.
-        def prep(sel_sub):
-            sel, sub = sel_sub
+        def prep(item):
             if not use_pallas:
+                sel, sub = item
                 with telemetry.span("sweep.put"):
                     cur = put(sub)
                 return sel, sub.shape[1], {key: cur for _, _, key in bands}, \
                     None
+            sel, width = item
             inputs, last = {}, None
             for _, _, key in bands:
                 if key not in inputs:
                     with telemetry.span("sweep.gap_columns"):
-                        gaps, last = _gap_columns(sub, key[1], key[0])
+                        gaps, last = _gap_columns(times, key[1], key[0],
+                                                  sel, width)
                     with telemetry.span("sweep.put"):
                         inputs[key] = _put_gaps(gaps, mesh)
-            return sel, sub.shape[1], inputs, last
+            return sel, width, inputs, last
 
-        work = _chunked_buckets(times, counts, chunk)
+        work = (_chunked_rows(counts, chunk) if use_pallas
+                else _chunked_buckets(times, counts, chunk))
         pending = take(work)
         if pending is None:
             return
