@@ -42,6 +42,19 @@ columnar :class:`repro.serving.apptable.AppTable` in three array passes:
      schedule converges to the oracle's in at most one resolution per
      ``_ensure_budget`` call that evicts.
 
+Spans and counters (``repro.telemetry``; spans are recorded only under a
+profiler, on the calling thread): ``cluster.merge`` (phase A and the
+end-time frame phase B reads); per policy-window chunk ``cluster.bucket``
+(the chunk's row copy), ``cluster.gap_columns``, ``cluster.put`` (the
+host-to-device put and the scan's dispatch) and ``cluster.pull`` (waiting
+for the scan, the copy back and the scatter into the window arrays);
+``cluster.replay`` (phase C), ``cluster.evict`` (phase D: the screen and
+every worker's fixed point) and ``cluster.results`` (loads and unloads,
+latencies, per-worker stats). Counters, per call: ``cluster.events``
+(events replayed), ``cluster.evictions``, ``cluster.evict_rounds``
+(fixed-point resolutions) and ``cluster.risky_workers`` (workers past the
+phase-D screen).
+
 Exactness contract (enforced by ``tests/test_cluster_conformance.py``):
 cold counts, per-app cold %, latencies and every
 load/unload/prewarm/**eviction** counter are *bit-identical* to the oracle
@@ -64,6 +77,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..core import policy_math
 from ..core.experiment import (FixedSpec, HybridSpec, NoUnloadSpec,
                                PolicySpec, SpesSpec, as_spec)
@@ -143,31 +157,36 @@ def as_table(workload, *, exec_s=None, memory_mb=None,
 # --------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnums=(1,))
-def _hybrid_windows_scan(gaps, cfg: policy_math.HybridStepConfig):
+@partial(jax.jit, static_argnums=(2,))
+def _hybrid_windows_scan(gaps, knobs: policy_math.HybridStepConfig,
+                         n_bins: int):
     """Scan the hybrid step over one chunk's end-time gap columns
     (``simulator._gap_columns``: differenced and binned in float64 on the
     host, so the device computes in int32/float32 only).
 
+    ``knobs`` is the config with ``n_bins`` taken out (``None``): its
+    leaves are traced, so a new margin, percentile or threshold runs the
+    scan already compiled; ``n_bins`` sizes the histogram and is static.
     Returns the residency bounds decided *at* each event (they govern the
     following gap) and the sticky any-step out-of-bounds-heavy flag that
     routes an app to the ARIMA post-pass.
     """
+    cfg = knobs._replace(n_bins=n_bins)
     load, unload, total, oob = _window_sequences_scan(gaps, cfg)
     return load, unload, jnp.any(
         policy_math.oob_heavy(total, oob, cfg.oob_threshold), axis=1)
 
 
-@partial(jax.jit, static_argnums=(1, 2))
-def _hybrid_windows_scan_sharded(gaps, cfg: policy_math.HybridStepConfig,
-                                 mesh):
+@partial(jax.jit, static_argnums=(2, 3))
+def _hybrid_windows_scan_sharded(gaps, knobs: policy_math.HybridStepConfig,
+                                 n_bins: int, mesh):
     """:func:`_hybrid_windows_scan` partitioned along the app axis of
-    ``mesh`` (outputs carry apps on axis 0; the config is a replicated
-    static). No collectives — shard outputs concatenate in fixed device
-    order, bit-identical to the unsharded scan."""
+    ``mesh`` (outputs carry apps on axis 0; the knobs are replicated). No
+    collectives — shard outputs concatenate in fixed device order,
+    bit-identical to the unsharded scan."""
     from ..distributed.scaleout import shard_along_apps
-    fn = lambda g: _hybrid_windows_scan(g, cfg)
-    return shard_along_apps(fn, mesh, (0,), 0)(gaps)
+    fn = lambda g, k: _hybrid_windows_scan(g, k, n_bins)
+    return shard_along_apps(fn, mesh, (0, None), 0)(gaps, knobs)
 
 
 @jax.jit
@@ -258,22 +277,33 @@ def _policy_windows(table: AppTable, spec: PolicySpec, e_min2d: np.ndarray,
     from ..distributed import scaleout
     hybrid = spec.to_config()
     cfg = _step_config_for(hybrid)
+    knobs = cfg._replace(n_bins=None)
     mesh = scaleout.mesh_for(devices)
     ua[:] = hybrid.standard_keep_alive       # zero-event rows: never read
     heavy = np.zeros(n, bool)
-    for sel, sub in _chunked_buckets(e_min2d, counts, app_chunk):
-        gaps = _put_gaps(_gap_columns(sub, cfg.bin_minutes, cfg.n_bins)[0],
-                         mesh)
-        if mesh is None:
-            la_seq, ua_seq, flag = _hybrid_windows_scan(gaps, cfg)
-        else:
-            la_seq, ua_seq, flag = _hybrid_windows_scan_sharded(gaps, cfg,
-                                                                mesh)
-        k = len(sel)
-        width = sub.shape[1]
-        la[sel, :width] = np.asarray(la_seq)[:k]
-        ua[sel, :width] = np.asarray(ua_seq)[:k]
-        heavy[sel] = np.asarray(flag)[:k]
+    chunks = _chunked_buckets(e_min2d, counts, app_chunk)
+    while True:
+        with telemetry.span("cluster.bucket"):
+            chunk = next(chunks, None)
+        if chunk is None:
+            break
+        sel, sub = chunk
+        with telemetry.span("cluster.gap_columns"):
+            gaps = _gap_columns(sub, cfg.bin_minutes, cfg.n_bins)[0]
+        with telemetry.span("cluster.put"):
+            gaps = _put_gaps(gaps, mesh)
+            if mesh is None:
+                la_seq, ua_seq, flag = _hybrid_windows_scan(
+                    gaps, knobs, cfg.n_bins)
+            else:
+                la_seq, ua_seq, flag = _hybrid_windows_scan_sharded(
+                    gaps, knobs, cfg.n_bins, mesh)
+        with telemetry.span("cluster.pull"):
+            k = len(sel)
+            width = sub.shape[1]
+            la[sel, :width] = np.asarray(la_seq)[:k]
+            ua[sel, :width] = np.asarray(ua_seq)[:k]
+            heavy[sel] = np.asarray(flag)[:k]
 
     # Forecast post-pass: the fused step carries no forecaster, so any app
     # whose OOB counter ever looked heavy (a superset of "the ARIMA branch
@@ -518,187 +548,201 @@ def _run_vector(table: AppTable, spec: PolicySpec, cluster: ClusterSpec,
             f"budget forever)")
 
     # ---- Phase A: the merged event stream -------------------------------
-    m_ev = table.times.shape[1]
-    valid = np.arange(m_ev)[None, :] < counts[:, None]
-    rows, cols = np.nonzero(valid)              # row-major: (app, k) order
-    n_events = len(rows)
-    t_flat = table.times[rows, cols].astype(np.float64) * MINUTE
-    order = np.lexsort((rows, t_flat))          # oracle sort: (t, app_idx)
-    rank = np.empty(n_events, np.int64)
-    rank[order] = np.arange(n_events)
+    with telemetry.span("cluster.merge"):
+        m_ev = table.times.shape[1]
+        valid = np.arange(m_ev)[None, :] < counts[:, None]
+        rows, cols = np.nonzero(valid)          # row-major: (app, k) order
+        n_events = len(rows)
+        t_flat = table.times[rows, cols].astype(np.float64) * MINUTE
+        order = np.lexsort((rows, t_flat))      # oracle sort: (t, app_idx)
+        rank = np.empty(n_events, np.int64)
+        rank[order] = np.arange(n_events)
 
-    x_flat = table.exec_s[rows].astype(np.float64)
-    if cluster.hedge is not None and n_events:
-        u1, u2 = cluster.hedge.event_uniforms(n_events)
-        x_flat = np.asarray(cluster.hedge.latency_from_uniforms(
-            x_flat, u1[rank], u2[rank]), np.float64)
-    e_flat = t_flat + x_flat
-    e_min_flat = e_flat / MINUTE
+        x_flat = table.exec_s[rows].astype(np.float64)
+        if cluster.hedge is not None and n_events:
+            u1, u2 = cluster.hedge.event_uniforms(n_events)
+            x_flat = np.asarray(cluster.hedge.latency_from_uniforms(
+                x_flat, u1[rank], u2[rank]), np.float64)
+        e_flat = t_flat + x_flat
+        e_min_flat = e_flat / MINUTE
+
+        e_min2d = np.full((n, m_ev), np.inf)
+        e_min2d[rows, cols] = e_min_flat
+    telemetry.count("cluster.events", n_events)
 
     # ---- Phase B: policy windows per gap --------------------------------
-    e_min2d = np.full((n, m_ev), np.inf)
-    e_min2d[rows, cols] = e_min_flat
     la2d, ua2d = _policy_windows(table, spec, e_min2d, counts, app_chunk,
                                  devices=devices)
-    la = la2d[rows, cols]
-    ua = ua2d[rows, cols]
-    ka_sec = (ua - la) * MINUTE                 # == keep_alive * MINUTE
 
     # ---- Phase C: closed-form gap replay --------------------------------
-    assign = table.worker_assignment(n_workers, cluster.balancing)
-    w_flat = assign[rows]
-    tick_src = np.lexsort((t_flat, w_flat))     # per-worker sorted arrivals
-    ticks_by_w = t_flat[tick_src]
-    woff = np.zeros(n_workers + 1, np.int64)
-    np.cumsum(np.bincount(w_flat, minlength=n_workers), out=woff[1:])
+    with telemetry.span("cluster.replay"):
+        la = la2d[rows, cols]
+        ua = ua2d[rows, cols]
+        ka_sec = (ua - la) * MINUTE             # == keep_alive * MINUTE
 
-    last = cols == counts[rows] - 1
-    first = cols == 0
-    nxt = np.full(n_events, np.inf)
-    nxt[~last] = t_flat[np.nonzero(~last)[0] + 1]
+        assign = table.worker_assignment(n_workers, cluster.balancing)
+        w_flat = assign[rows]
+        tick_src = np.lexsort((t_flat, w_flat))  # per-worker sorted arrivals
+        ticks_by_w = t_flat[tick_src]
+        woff = np.zeros(n_workers + 1, np.int64)
+        np.cumsum(np.bincount(w_flat, minlength=n_workers), out=woff[1:])
 
-    stay = la <= 0.0                            # keep loaded through the gap
-    u_stay = e_flat + ua * MINUTE               # expiry schedule (stay)
-    p_pre = e_flat + la * MINUTE                # pre-warm schedule (else)
+        last = cols == counts[rows] - 1
+        first = cols == 0
+        nxt = np.full(n_events, np.inf)
+        nxt[~last] = t_flat[np.nonzero(~last)[0] + 1]
 
-    # Stay branch: unloaded at the first tick past the expiry — which
-    # exists whenever the next arrival is cold; the run end finalizes the
-    # last gap when no tick ever reaches it.
-    need_u = stay & ((nxt >= u_stay) | last)
-    ut_stay = np.full(n_events, np.inf)
-    ui_stay = np.full(n_events, -1, np.int64)
-    ut_stay[need_u], ui_stay[need_u] = _first_tick_ge(
-        ticks_by_w, woff, tick_src, w_flat[need_u], u_stay[need_u])
+        stay = la <= 0.0                        # keep loaded through the gap
+        u_stay = e_flat + ua * MINUTE           # expiry schedule (stay)
+        p_pre = e_flat + la * MINUTE            # pre-warm schedule (else)
 
-    # Pre-warm branch: unloaded immediately at the execution end; the fire
-    # happens at the first tick past the schedule unless the app's own next
-    # arrival (which cancels the pre-warm) comes first.
-    pre = ~stay
-    tau = np.full(n_events, np.inf)
-    tau_i = np.full(n_events, -1, np.int64)
-    tau[pre], tau_i[pre] = _first_tick_ge(
-        ticks_by_w, woff, tick_src, w_flat[pre], p_pre[pre])
-    fired = pre & np.isfinite(tau) & (last | (tau <= nxt))
-    q_fire = tau + ka_sec                       # post-fire expiry schedule
-    need_f = fired & ((nxt >= q_fire) | last)
-    ut_fire = np.full(n_events, np.inf)
-    ui_fire = np.full(n_events, -1, np.int64)
-    ut_fire[need_f], ui_fire[need_f] = _first_tick_ge(
-        ticks_by_w, woff, tick_src, w_flat[need_f], q_fire[need_f])
+        # Stay branch: unloaded at the first tick past the expiry — which
+        # exists whenever the next arrival is cold; the run end finalizes
+        # the last gap when no tick ever reaches it.
+        need_u = stay & ((nxt >= u_stay) | last)
+        ut_stay = np.full(n_events, np.inf)
+        ui_stay = np.full(n_events, -1, np.int64)
+        ut_stay[need_u], ui_stay[need_u] = _first_tick_ge(
+            ticks_by_w, woff, tick_src, w_flat[need_u], u_stay[need_u])
 
-    # Cold verdicts: event k is cold iff gap k-1 lost the image.
-    next_cold = np.where(stay, nxt >= u_stay,
-                         np.where(fired, nxt >= q_fire, True))
-    cold = np.empty(n_events, bool)
-    cold[first] = True
-    not_first = np.nonzero(~first)[0]
-    cold[not_first] = next_cold[not_first - 1]
+        # Pre-warm branch: unloaded immediately at the execution end; the
+        # fire happens at the first tick past the schedule unless the app's
+        # own next arrival (which cancels the pre-warm) comes first.
+        pre = ~stay
+        tau = np.full(n_events, np.inf)
+        tau_i = np.full(n_events, -1, np.int64)
+        tau[pre], tau_i[pre] = _first_tick_ge(
+            ticks_by_w, woff, tick_src, w_flat[pre], p_pre[pre])
+        fired = pre & np.isfinite(tau) & (last | (tau <= nxt))
+        q_fire = tau + ka_sec                   # post-fire expiry schedule
+        need_f = fired & ((nxt >= q_fire) | last)
+        ut_fire = np.full(n_events, np.inf)
+        ui_fire = np.full(n_events, -1, np.int64)
+        ut_fire[need_f], ui_fire[need_f] = _first_tick_ge(
+            ticks_by_w, woff, tick_src, w_flat[need_f], q_fire[need_f])
+
+        # Cold verdicts: event k is cold iff gap k-1 lost the image.
+        next_cold = np.where(stay, nxt >= u_stay,
+                             np.where(fired, nxt >= q_fire, True))
+        cold = np.empty(n_events, bool)
+        cold[first] = True
+        not_first = np.nonzero(~first)[0]
+        cold[not_first] = next_cold[not_first - 1]
 
     # ---- Phase D: HBM evictions to a fixed point ------------------------
-    # Cheap pessimistic screen first: a worker whose assigned apps all fit
-    # at once can never evict; only workers past the sum test replay their
-    # exact processing-order occupancy (and most find no violation).
-    wb = table.weight_bytes.astype(np.float64)
-    wb_flat = wb[rows]
-    evicted = np.zeros(n_events, bool)
-    evict_time = np.zeros(n_events)
-    overflow_w = np.zeros(n_workers, np.int64)
-    active = counts > 0
-    if np.isfinite(budget) and n_events:
-        per_w_assigned = np.bincount(assign[active], weights=wb[active],
-                                     minlength=n_workers)
-        risky = np.nonzero(per_w_assigned > budget)[0]
-        if len(risky):
-            tie = _app_tie_ranks(table)
-            t_by_rank = t_flat[order]
-            rounds_left = (max_eviction_rounds if max_eviction_rounds
-                           is not None else np.inf)
-            for w in risky:
-                j_w = np.nonzero(w_flat == w)[0]
-                ev_l, evt_l, n_over, used = _evict_worker(
-                    j_w, budget, rows=rows, rank=rank, t_by_rank=t_by_rank,
-                    wb=wb_flat, tie=tie, cold=cold, stay=stay, pre=pre,
-                    fired=fired, need_u=need_u, need_f=need_f,
-                    ui_stay=ui_stay, ui_fire=ui_fire, tau_i=tau_i,
-                    u_stay=u_stay, q_fire=q_fire, p_pre=p_pre,
-                    max_rounds=rounds_left)
-                evicted[j_w] = ev_l
-                evict_time[j_w] = evt_l
-                overflow_w[w] = n_over
-                rounds_left -= used
+    with telemetry.span("cluster.evict"):
+        # Cheap pessimistic screen first: a worker whose assigned apps all
+        # fit at once can never evict; only workers past the sum test replay
+        # their exact processing-order occupancy (and most find no
+        # violation).
+        wb = table.weight_bytes.astype(np.float64)
+        wb_flat = wb[rows]
+        evicted = np.zeros(n_events, bool)
+        evict_time = np.zeros(n_events)
+        overflow_w = np.zeros(n_workers, np.int64)
+        active = counts > 0
+        risky, rounds = np.zeros(0, np.int64), 0
+        if np.isfinite(budget) and n_events:
+            per_w_assigned = np.bincount(assign[active], weights=wb[active],
+                                         minlength=n_workers)
+            risky = np.nonzero(per_w_assigned > budget)[0]
+            if len(risky):
+                tie = _app_tie_ranks(table)
+                t_by_rank = t_flat[order]
+                max_rounds = (max_eviction_rounds if max_eviction_rounds
+                              is not None else np.inf)
+                for w in risky:
+                    j_w = np.nonzero(w_flat == w)[0]
+                    ev_l, evt_l, n_over, used = _evict_worker(
+                        j_w, budget, rows=rows, rank=rank,
+                        t_by_rank=t_by_rank, wb=wb_flat, tie=tie, cold=cold,
+                        stay=stay, pre=pre, fired=fired, need_u=need_u,
+                        need_f=need_f, ui_stay=ui_stay, ui_fire=ui_fire,
+                        tau_i=tau_i, u_stay=u_stay, q_fire=q_fire,
+                        p_pre=p_pre, max_rounds=max_rounds - rounds)
+                    evicted[j_w] = ev_l
+                    evict_time[j_w] = evt_l
+                    overflow_w[w] = n_over
+                    rounds += used
+    telemetry.count("cluster.risky_workers", len(risky))
+    telemetry.count("cluster.evict_rounds", rounds)
+    telemetry.count("cluster.evictions", int(evicted.sum()))
 
-    # Loads and unloads (time, worker, bytes) for residency + stats. An
-    # evicted span's scheduled expiry never happens — its unload is the
-    # eviction itself, at the evicting load's tick time.
-    sched_u = need_u & ~evicted
-    sched_f = need_f & ~evicted
-    load_m = [cold, fired]
-    load_t = [t_flat[cold], tau[fired]]
-    unload_m = [pre, sched_u, sched_f, evicted]
-    # Expiries missing their tick are finalized at the run end.
-    unload_t = [e_flat[pre],
-                np.where(np.isfinite(ut_stay[sched_u]), ut_stay[sched_u],
-                         t_end),
-                np.where(np.isfinite(ut_fire[sched_f]), ut_fire[sched_f],
-                         t_end),
-                evict_time[evicted]]
+    with telemetry.span("cluster.results"):
+        # Loads and unloads (time, worker, bytes) for residency + stats. An
+        # evicted span's scheduled expiry never happens — its unload is the
+        # eviction itself, at the evicting load's tick time.
+        sched_u = need_u & ~evicted
+        sched_f = need_f & ~evicted
+        load_m = [cold, fired]
+        load_t = [t_flat[cold], tau[fired]]
+        unload_m = [pre, sched_u, sched_f, evicted]
+        # Expiries missing their tick are finalized at the run end.
+        unload_t = [e_flat[pre],
+                    np.where(np.isfinite(ut_stay[sched_u]),
+                             ut_stay[sched_u], t_end),
+                    np.where(np.isfinite(ut_fire[sched_f]),
+                             ut_fire[sched_f], t_end),
+                    evict_time[evicted]]
 
-    lw = np.concatenate([w_flat[m] for m in load_m]) if n_events else \
-        np.zeros(0, np.int64)
-    uw = np.concatenate([w_flat[m] for m in unload_m]) if n_events else \
-        np.zeros(0, np.int64)
-    lr = np.concatenate([rows[m] for m in load_m]) if n_events else \
-        np.zeros(0, np.int64)
-    ur = np.concatenate([rows[m] for m in unload_m]) if n_events else \
-        np.zeros(0, np.int64)
-    lb = wb[lr]
-    ub = wb[ur]
-    lt = np.concatenate(load_t) if n_events else np.zeros(0)
-    ut = np.concatenate(unload_t) if n_events else np.zeros(0)
+        lw = np.concatenate([w_flat[m] for m in load_m]) if n_events else \
+            np.zeros(0, np.int64)
+        uw = np.concatenate([w_flat[m] for m in unload_m]) if n_events else \
+            np.zeros(0, np.int64)
+        lr = np.concatenate([rows[m] for m in load_m]) if n_events else \
+            np.zeros(0, np.int64)
+        ur = np.concatenate([rows[m] for m in unload_m]) if n_events else \
+            np.zeros(0, np.int64)
+        lb = wb[lr]
+        ub = wb[ur]
+        lt = np.concatenate(load_t) if n_events else np.zeros(0)
+        ut = np.concatenate(unload_t) if n_events else np.zeros(0)
 
-    n_loads = np.bincount(lr, minlength=n)
-    n_unloads = np.bincount(ur, minlength=n)
-    if not np.array_equal(n_loads, n_unloads):  # pragma: no cover
-        raise AssertionError("cluster_vector invariant violated: "
-                             "per-app loads != unloads")
+        n_loads = np.bincount(lr, minlength=n)
+        n_unloads = np.bincount(ur, minlength=n)
+        if not np.array_equal(n_loads, n_unloads):  # pragma: no cover
+            raise AssertionError("cluster_vector invariant violated: "
+                                 "per-app loads != unloads")
 
-    # ---- Results --------------------------------------------------------
-    base_cold = BASE_LOAD_LATENCY + wb / H2D_BANDWIDTH
-    start_lat = np.where(
-        cold, base_cold[rows] + np.where(first, COMPILE_MISS_LATENCY, 0.0),
-        0.0)
-    lat = np.empty(n_events)
-    lat[rank] = start_lat + x_flat              # oracle (arrival) order
+        # ---- Results ----------------------------------------------------
+        base_cold = BASE_LOAD_LATENCY + wb / H2D_BANDWIDTH
+        start_lat = np.where(
+            cold,
+            base_cold[rows] + np.where(first, COMPILE_MISS_LATENCY, 0.0),
+            0.0)
+        lat = np.empty(n_events)
+        lat[rank] = start_lat + x_flat          # oracle (arrival) order
 
-    cold_per_app = np.bincount(rows, weights=cold.astype(np.float64),
-                               minlength=n)
-    inv = counts.astype(np.float64)
-    # Per-app first, per-worker second: the load/unload time sums cancel
-    # within each app's handful of events instead of across the fleet,
-    # keeping resident time at float64 accumulation accuracy.
-    res_app = (np.bincount(ur, weights=ut * ub, minlength=n)
-               - np.bincount(lr, weights=lt * lb, minlength=n))
-    resident_bs = np.bincount(assign, weights=res_app, minlength=n_workers)
+        cold_per_app = np.bincount(rows, weights=cold.astype(np.float64),
+                                   minlength=n)
+        inv = counts.astype(np.float64)
+        # Per-app first, per-worker second: the load/unload time sums cancel
+        # within each app's handful of events instead of across the fleet,
+        # keeping resident time at float64 accumulation accuracy.
+        res_app = (np.bincount(ur, weights=ut * ub, minlength=n)
+                   - np.bincount(lr, weights=lt * lb, minlength=n))
+        resident_bs = np.bincount(assign, weights=res_app,
+                                  minlength=n_workers)
 
-    stats = []
-    cold_w = np.bincount(w_flat[cold], minlength=n_workers)
-    warm_w = (np.bincount(w_flat, minlength=n_workers) - cold_w)
-    fire_w = np.bincount(w_flat[fired], minlength=n_workers)
-    unl_w = np.bincount(uw, minlength=n_workers)   # includes evictions
-    evict_w = np.bincount(w_flat[evicted], minlength=n_workers)
-    moved_w = np.bincount(lw, weights=lb, minlength=n_workers)
-    for w in range(n_workers):
-        stats.append(dict(
-            cold_starts=int(cold_w[w]), warm_starts=int(warm_w[w]),
-            prewarms=int(fire_w[w]), unloads=int(unl_w[w]),
-            evictions=int(evict_w[w]),
-            budget_overflows=int(overflow_w[w]),
-            bytes_moved=float(moved_w[w]),
-            resident_byte_seconds=float(resident_bs[w])))
+        stats = []
+        cold_w = np.bincount(w_flat[cold], minlength=n_workers)
+        warm_w = (np.bincount(w_flat, minlength=n_workers) - cold_w)
+        fire_w = np.bincount(w_flat[fired], minlength=n_workers)
+        unl_w = np.bincount(uw, minlength=n_workers)   # includes evictions
+        evict_w = np.bincount(w_flat[evicted], minlength=n_workers)
+        moved_w = np.bincount(lw, weights=lb, minlength=n_workers)
+        for w in range(n_workers):
+            stats.append(dict(
+                cold_starts=int(cold_w[w]), warm_starts=int(warm_w[w]),
+                prewarms=int(fire_w[w]), unloads=int(unl_w[w]),
+                evictions=int(evict_w[w]),
+                budget_overflows=int(overflow_w[w]),
+                bytes_moved=float(moved_w[w]),
+                resident_byte_seconds=float(resident_bs[w])))
 
-    restored = (cluster.checkpoint_at_minute is not None and n_events > 0
-                and bool(np.any(
-                    t_flat >= cluster.checkpoint_at_minute * MINUTE)))
+        restored = (cluster.checkpoint_at_minute is not None
+                    and n_events > 0 and bool(np.any(
+                        t_flat >= cluster.checkpoint_at_minute * MINUTE)))
     return ClusterResult(
         cold_pct_per_app=100.0 * cold_per_app / np.maximum(inv, 1),
         latencies_s=lat,

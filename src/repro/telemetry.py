@@ -17,6 +17,14 @@ with leaf spans ``sweep.pad``, ``sweep.bucket``, ``sweep.gap_columns``,
 ``sweep.arima_replay`` and ``sweep.fill``; counters ``sweep.event_steps``
 (configs x real events stepped) and ``sweep.app_steps`` (configs x rows x
 width the scans step through, the kernel's tile padding included).
+
+The spans and counters of one invoker-cluster replay
+(``serving.cluster_vector``, the vectorized engine): leaf spans
+``cluster.merge`` (phase A), ``cluster.bucket``, ``cluster.gap_columns``,
+``cluster.put`` and ``cluster.pull`` (phase B, per chunk),
+``cluster.replay`` (phase C), ``cluster.evict`` (phase D) and
+``cluster.results``; counters ``cluster.events``, ``cluster.evictions``,
+``cluster.evict_rounds`` and ``cluster.risky_workers``.
 """
 from __future__ import annotations
 
