@@ -268,7 +268,8 @@ class EngineOptions:
     include_trailing: bool = True     # account waste after the last event
     app_chunk: Optional[int] = None   # apps per device chunk (None: auto,
     #                                   scaled down by the config-axis size)
-    tile_apps: int = 512              # Pallas kernel app-tile
+    tile_apps: int = 1024             # Pallas kernel app-tile (one vreg
+    #                                   of apps: 8 sublanes x 128 lanes)
     devices: Union[None, int, str] = None   # shard the app axis: None (off),
     #                                   an int device count (1 exercises the
     #                                   sharded path), or "auto" (every

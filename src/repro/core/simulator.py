@@ -29,9 +29,10 @@ single-source policy math in :mod:`repro.core.policy_math`:
     config through the batched forecasting subsystem and their results
     overridden (the paper: ARIMA governs ~0.7% of invocations).
 
-  * On TPU the sweep step runs as a Pallas kernel
-    (:func:`repro.kernels.histogram.fused_hybrid_sweep_step_pallas`) in
-    float32, with the per-config knobs delivered as an SMEM config block
+  * On TPU the sweep scan runs as a Pallas kernel
+    (:func:`repro.kernels.histogram.hybrid_sweep_scan_pallas`) in
+    float32 that walks each chunk's event columns itself, the state held
+    in VMEM, with the per-config knobs delivered as an SMEM config block
     via scalar prefetch; ``engine="pallas"`` exercises it in interpret
     mode elsewhere (decided from the backend at call time).
 
@@ -608,30 +609,21 @@ def _hybrid_sweep_scan(times, blk: policy_math.HybridSweepBlock,
 
 @partial(jax.jit, static_argnums=(3, 4, 5))
 def _hybrid_sweep_scan_pallas(gaps, cfg_i32, cfg_f32, n_bins: int,
-                              interpret: bool = False, tile_apps: int = 512):
-    """Same sweep, stepping through the Pallas TPU kernel (float32). The
+                              interpret: bool = False, tile_apps: int = 1024):
+    """Same sweep, walked by the Pallas TPU scan kernel (float32). The
     driver feeds each chunk as host-made gap columns ``gaps`` = (it_lo,
     it_hi, code), each [n, width] — see :func:`_gap_columns`. The config
-    block rides in SMEM via scalar prefetch; per-config state is carried
-    unfactored (grid (S, app tiles)) in the kernel's lane-dense layout:
-    apps on the last axis, [S, 1, n] vectors, [S, n_bins, n] cumulative
-    counts."""
-    from ..kernels.histogram import fused_hybrid_sweep_step_pallas
+    block rides in SMEM via scalar prefetch; per-config state is
+    unfactored (grid (S, app tiles, time blocks)) in the kernel's
+    lane-dense layout: apps on the last axis, [S, 1, n] vectors,
+    [S, n_bins, n] cumulative counts, and the columns [width, n]. The
+    kernel pads the apps to its tile and the steps to its time block
+    (:func:`repro.kernels.histogram.scan_blocks`)."""
+    from ..kernels import histogram
 
     S = cfg_i32.shape[0]
-    # Pad the app dimension to the kernel tile ONCE, outside the scan —
-    # otherwise the kernel wrapper re-pads and re-slices the whole carry
-    # (including [S, n_bins, n] cum) on every scan step. Padded rows carry
-    # no events.
-    it_lo, it_hi, code = gaps
-    n_real = code.shape[0]
-    pad = _tile_pad(n_real, tile_apps)
-    if pad:
-        rows = lambda x, fill: jnp.concatenate(
-            [x, jnp.full((pad, x.shape[1]), fill, x.dtype)])
-        it_lo, it_hi = rows(it_lo, 0.0), rows(it_hi, 0.0)
-        code = rows(code, policy_math.GAP_PAD)
-    n = code.shape[0]
+    it_lo, it_hi, code = (x.T for x in gaps)
+    n = code.shape[1]
     vec = lambda dt: jnp.zeros((S, 1, n), dt)
     init = (
         jnp.zeros((S, n_bins, n), jnp.int32),
@@ -643,18 +635,11 @@ def _hybrid_sweep_scan_pallas(gaps, cfg_i32, cfg_f32, n_bins: int,
         vec(jnp.int32),
         vec(jnp.float32),
     )
-
-    def step(carry, column):
-        out = fused_hybrid_sweep_step_pallas(
-            *column, *carry, cfg_i32, cfg_f32, tile_apps=tile_apps,
-            interpret=interpret)
-        return out, None
-
-    columns = tuple(x.T[:, None, :] for x in (it_lo, it_hi, code))
     with jax.named_scope("sweep.scan"):
-        carry, _ = jax.lax.scan(step, init, columns)
-    carry = tuple(c[..., :n_real] for c in carry)
-    (cum, oob, _, _, prewarm, unload_at, cold, waste) = carry
+        (_, oob, _, _, prewarm, unload_at, cold, waste) = \
+            histogram.hybrid_sweep_scan_pallas(
+                it_lo, it_hi, code, init, cfg_i32, cfg_f32,
+                tile_apps=tile_apps, interpret=interpret)
     return cold[:, 0], waste[:, 0], oob[:, 0] > 0, prewarm[:, 0], \
         unload_at[:, 0]
 
@@ -847,7 +832,7 @@ def _run_hybrid_sweep(trace: Trace, hybrids: Sequence[HybridConfig],
                       include_trailing: bool = True, *,
                       app_chunk: Optional[int] = None,
                       use_pallas: Optional[bool] = None,
-                      tile_apps: int = 512,
+                      tile_apps: int = 1024,
                       padded=None, devices=None) -> dict:
     """S hybrid configs over one bucketed/chunked/rebased trace pass.
 
@@ -985,10 +970,12 @@ def _run_hybrid_sweep(trace: Trace, hybrids: Sequence[HybridConfig],
             nxt = take(work)
             pending = None if nxt is None else prep(nxt)
             n_events = int(counts[sel].sum())
-            rows = _scan_rows(len(sel), ndev, tile_apps if use_pallas else 0)
+            tile = tile_apps if use_pallas else 0
             for idx, fn, key in bands:
+                rows, steps = _scan_extent(len(sel), ndev, width, key[0],
+                                           tile)
                 telemetry.count("sweep.event_steps", len(idx) * n_events)
-                telemetry.count("sweep.app_steps", len(idx) * rows * width)
+                telemetry.count("sweep.app_steps", len(idx) * rows * steps)
                 with telemetry.span("sweep.dispatch"):
                     res = fn(inputs[key])
                 # [..., :len(sel)] drops the masked mesh-padding rows (a
@@ -1036,21 +1023,19 @@ def _run_hybrid_sweep(trace: Trace, hybrids: Sequence[HybridConfig],
                 final_keep_alive=keep)
 
 
-def _scan_rows(n: int, ndev: int, tile_apps: int) -> int:
-    """Rows a scan steps through for an ``n``-app chunk: the mesh pads
-    the rows to a multiple of its ``ndev`` devices, then the Pallas scan
-    pads each device's rows to its kernel tile as
-    :func:`_hybrid_sweep_scan_pallas` does (``tile_apps`` 0: no tile)."""
+def _scan_extent(n: int, ndev: int, width: int, n_bins: int,
+                 tile_apps: int) -> tuple:
+    """(rows, steps) a scan takes through a ``width``-event chunk of ``n``
+    apps. The mesh pads the rows to a multiple of its ``ndev`` devices;
+    the Pallas scan kernel pads each device's rows to its app tile and the
+    steps to its time block (:func:`repro.kernels.histogram.scan_blocks`).
+    The jnp scan (``tile_apps`` 0) takes ``width`` steps."""
     per = -(-n // ndev)
-    if tile_apps:
-        per += _tile_pad(per, tile_apps)
-    return per * ndev
-
-
-def _tile_pad(n: int, tile_apps: int) -> int:
-    """Rows the Pallas scan adds to ``n`` app rows to fill its kernel
-    tiles (a chunk below one tile is its own tile)."""
-    return (-n) % min(tile_apps, n) if n else 0
+    if not tile_apps or not per:
+        return per * ndev, width
+    from ..kernels.histogram import scan_blocks
+    ta, tb = scan_blocks(per, width, n_bins, tile_apps)
+    return -(-per // ta) * ta * ndev, -(-width // tb) * tb
 
 
 # -- pre-sweep batched engine (benchmark/regression baseline) ----------------

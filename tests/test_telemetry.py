@@ -15,9 +15,10 @@ import jax
 
 from repro import telemetry
 from repro.core.experiment import EngineOptions, HybridSpec, sweep
+from repro.core.histogram import HistogramConfig
 from repro.core.policy import HybridConfig
 from repro.core.simulator import (_build_pallas_cfg, _gap_columns,
-                                  _hybrid_sweep_scan_pallas, _scan_rows)
+                                  _hybrid_sweep_scan_pallas, _scan_extent)
 from repro.core.workload import Trace
 
 DURATION = 1440.0
@@ -123,6 +124,20 @@ def test_sweep_counters_equal_a_hand_count(engine, app_steps):
     }
 
 
+def test_app_steps_count_the_time_block_padding(monkeypatch):
+    """With no VMEM to plan with, the kernel walks the 70-event bucket in
+    9 blocks of 8 steps: 72 steps, 2 of them padding."""
+    from repro.kernels import histogram
+    monkeypatch.setattr(histogram, "_SCAN_VMEM_BYTES", 0)
+    assert histogram.scan_blocks(2, 70, 48, 2) == (2, 8)
+    before = telemetry.counters()
+    sweep(_trace(), SPECS, engine="pallas", options=OPTS)
+    after = telemetry.counters()
+    # the <=64 bucket's 5 steps fit one block; the <=512 bucket's 70 do not
+    assert after["sweep.app_steps"] - before.get("sweep.app_steps", 0) == \
+        3 * (4 * 5 + 1 * 5 + 2 * 72)
+
+
 def test_counters_are_a_copy():
     telemetry.count("test.copy", 2)
     seen = telemetry.counters()
@@ -139,29 +154,37 @@ def test_counters_are_a_copy():
     (10, 4, 0, 12),            # the mesh pads rows to its devices
     (10, 4, 2, 16),            # 3 rows a device, padded to 4 by the tile
     (1000, 4, 128, 1024),      # 250 a device, padded to 256
+    (2560, 1, 1024, 3072),     # packed tiles of whole vregs
 ])
 def test_scan_rows_follow_the_mesh_and_kernel_padding(n, ndev, tile, rows):
-    assert _scan_rows(n, ndev, tile) == rows
+    assert _scan_extent(n, ndev, 6, 60, tile) == (rows, 6)
 
 
-def _scan_carry_rows(jaxpr):
-    """The app rows of the first ``scan``'s carry found in ``jaxpr``."""
+def _kernel_state_rows(jaxpr):
+    """The app rows of the state the first ``pallas_call`` found in
+    ``jaxpr`` carries (its first output, ``cum``)."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            return eqn.outvars[0].aval.shape[-1]
+        if eqn.primitive.name == "pallas_call":
+            # [S, n_bins, rows], or [S, n_bins, rows / 128, 128] packed
+            return int(np.prod(eqn.outvars[0].aval.shape[2:]))
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            rows = _scan_carry_rows(sub)
+            rows = _kernel_state_rows(sub)
             if rows is not None:
                 return rows
     return None
 
 
-@pytest.mark.parametrize("n,tile", [(3, 2), (5, 4), (4, 4), (3, 8)])
-def test_scan_rows_equal_the_rows_the_pallas_scan_carries(n, tile):
-    ci, cf = _build_pallas_cfg([HybridConfig(use_arima=False)])
+@pytest.mark.parametrize("n_bins", [60, 240])
+@pytest.mark.parametrize("n,tile", [(3, 2), (5, 4), (4, 4), (3, 8),
+                                    (1100, 1024)])
+def test_scan_rows_equal_the_rows_the_pallas_scan_carries(n, tile, n_bins):
+    ci, cf = _build_pallas_cfg([HybridConfig(
+        histogram=HistogramConfig(range_minutes=float(n_bins)),
+        use_arima=False)])
     times = np.sort(np.random.default_rng(n).uniform(0.0, DURATION, (n, 6)))
-    gaps, _ = _gap_columns(times, 1.0, 240)
+    gaps, _ = _gap_columns(times, 1.0, n_bins)
     jaxpr = jax.make_jaxpr(_hybrid_sweep_scan_pallas,
                            static_argnums=(3, 4, 5))(
-        gaps, ci, cf, 240, True, tile)
-    assert _scan_carry_rows(jaxpr.jaxpr) == _scan_rows(n, 1, tile)
+        gaps, ci, cf, n_bins, True, tile)
+    assert _kernel_state_rows(jaxpr.jaxpr) == _scan_extent(
+        n, 1, 6, n_bins, tile)[0]
