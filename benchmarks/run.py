@@ -25,15 +25,14 @@ def main() -> None:
     use_compile_cache()
 
     from . import (cluster_sim, fig_cluster, fig_exec_mem, fig_policy,
-                   fig_workload, forecast, kernel_bench, policy_overhead,
-                   policy_sweep, roofline, scaleout, trace_gen)
+                   fig_workload, forecast, kernel_bench, policy_sweep,
+                   roofline, scaleout, trace_gen)
     modules = {
         "fig_workload": lambda: fig_workload.run(),
         "fig_exec_mem": lambda: fig_exec_mem.run(),
         "fig_policy": lambda: fig_policy.run(n_apps=args.apps),
         "fig_cluster": lambda: fig_cluster.run(),
         "cluster_sim": lambda: cluster_sim.run(),
-        "policy_overhead": lambda: policy_overhead.run(),
         "policy_sweep": lambda: policy_sweep.run(),
         "scaleout": lambda: scaleout.run(),
         "trace_gen": lambda: trace_gen.run(),

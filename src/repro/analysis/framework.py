@@ -114,8 +114,7 @@ class LintConfig:
         ("simulate", "experiment.run(trace, spec)"),
         ("simulate_fixed_batch", "experiment.run(trace, FixedSpec(ka))"),
         ("simulate_hybrid_batch", "experiment.run(trace, HybridSpec(...))"),
-        ("simulate_hybrid_batch_reference",
-         'experiment.run(trace, spec, engine="reference")'),
+        ("simulate_hybrid_batch_reference", "experiment.run(trace, spec)"),
     )
     removed_attrs: Tuple[Tuple[str, str], ...] = (
         ("synthesize", "WorkloadSpec.uniform(...).materialize()"),

@@ -13,8 +13,8 @@ promoted):
     itself* an absolute-time column (``times``, ``t_abs``, ...) straight to
     float32 is flagged unless the enclosing function also rebases (calls a
     ``*rebase*`` helper). Multi-week absolute clocks do not fit float32 —
-    per-chunk float64 rebasing before the cast is the PR-2 contract
-    (``simulator._rebase_chunk``).
+    difference or rebase in float64 before the cast (the Pallas engine
+    sends the kernel float64-differenced gaps, ``simulator._gap_columns``).
 """
 from __future__ import annotations
 
@@ -135,7 +135,8 @@ class X64Discipline(Rule):
             module, node,
             f"float32 cast of absolute-time column {tn!r} without per-chunk "
             "rebasing: multi-week clocks lose sub-minute IAT structure in "
-            "float32 (see simulator._rebase_chunk)")
+            "float32 (difference the clock in float64 first, as "
+            "simulator._gap_columns does)")
 
     @staticmethod
     def _cast_subject(node: ast.Call) -> Optional[ast.AST]:

@@ -24,7 +24,7 @@ is accepted, a declarative :class:`~repro.core.workload_spec.WorkloadSpec`
     for t, res in enumerate(grid_2d):          # (T, S) SweepGrid
         print(grid_2d.trace_name(t), res.row(0).cold_pct_percentile(75))
 
-Each trace is bucketed/chunked/rebased ONCE (``to_padded`` hoisted out of
+Each trace is bucketed/chunked ONCE (``to_padded`` hoisted out of
 the per-family engines) and reused across every policy configuration; rows
 of the (T, S) grid are bit-identical to the corresponding single-trace
 ``run()`` calls on every engine.
@@ -34,7 +34,7 @@ their numeric knobs), each ``.build()``-able into the stateful
 :class:`repro.core.policy.Policy` objects the scalar oracle and the serving
 layer consume. ``sweep`` stacks same-family specs into a traced config axis
 and drives the factored sweep engines in :mod:`repro.core.simulator`: the
-trace is bucketed, chunked, rebased, and scanned ONCE for all S configs
+trace is bucketed, chunked, and scanned ONCE for all S configs
 instead of S times, with histogram sufficient statistics shared across
 configs that agree on the histogram shape (see
 :class:`repro.core.policy_math.HybridSweepBlock`).
@@ -51,11 +51,9 @@ Engines (``engine=`` on both ``run`` and ``sweep``):
     interpreted elsewhere — decided from the backend at call time),
     gaps differenced and binned on the host in float64, SMEM config block
     via scalar prefetch.
-  * ``"reference"`` — the pre-sweep per-step-cumsum float32 engine, one
-    config at a time (the benchmark baseline).
 
-The fixed/no-unload family has no histogram state; its ``"pallas"`` and
-``"reference"`` engines alias the (already exact) float64 fused sweep.
+The fixed/no-unload and SPES families have no histogram state; their
+``"pallas"`` engine aliases the (already exact) float64 fused sweep.
 
 Every engine's rows are bit-identical on cold counts, invocations, and
 final windows to single-config ``run()`` and to the float64 scalar oracle
@@ -75,8 +73,7 @@ from .histogram import HistogramConfig
 from .policy import (FixedKeepAlivePolicy, HybridConfig, HybridHistogramPolicy,
                      NoUnloadingPolicy, Policy, SpesConfig, SpesPolicy)
 from .simulator import (SimResult, _run_fixed_sweep, _run_hybrid_sweep,
-                        _run_spes_sweep, _simulate_hybrid_batch_reference,
-                        simulate_scalar)
+                        _run_spes_sweep, simulate_scalar)
 from .workload import Trace
 from .workload_spec import WorkloadSpec, _register_pytree
 
@@ -86,7 +83,7 @@ __all__ = [
     "as_trace", "run", "sweep",
 ]
 
-ENGINES = ("auto", "scalar", "fused", "pallas", "reference")
+ENGINES = ("auto", "scalar", "fused", "pallas")
 
 
 # PolicySpec and WorkloadSpec families share one pytree-registration
@@ -278,7 +275,7 @@ class EngineOptions:
     #                                   repro.distributed.scaleout. Applies
     #                                   to the vectorized sweep engines and
     #                                   the cluster policy-window scan;
-    #                                   "scalar"/"reference" ignore it.
+    #                                   "scalar" ignores it.
     max_eviction_rounds: Optional[int] = None   # cluster cells only: cap
     #                                   the HBM-eviction fixed point; past
     #                                   it the cell falls back to the
@@ -430,7 +427,7 @@ def _sweep_families(trace: Trace, specs: Sequence, eng: str,
         padded = trace.to_padded()
     if window_idx:
         # No histogram state in this family — the float64 fused sweep is
-        # already oracle-exact, so "pallas"/"reference" alias it.
+        # already oracle-exact, so "pallas" aliases it.
         out = _run_fixed_sweep(trace, [specs[s].keep_alive
                                        for s in window_idx],
                                opts.include_trailing, padded=padded,
@@ -438,20 +435,15 @@ def _sweep_families(trace: Trace, specs: Sequence, eng: str,
         fill(window_idx, out)
     if hybrid_idx:
         cfgs = [specs[s].to_config() for s in hybrid_idx]
-        if eng == "reference":
-            for s, cfg in zip(hybrid_idx, cfgs):
-                fill([s], _simulate_hybrid_batch_reference(
-                    trace, cfg, opts.include_trailing, padded=padded))
-        else:
-            out = _run_hybrid_sweep(
-                trace, cfgs, opts.include_trailing,
-                app_chunk=opts.app_chunk, use_pallas=(eng == "pallas"),
-                tile_apps=opts.tile_apps,
-                padded=padded, devices=opts.devices)
-            fill(hybrid_idx, out)
+        out = _run_hybrid_sweep(
+            trace, cfgs, opts.include_trailing,
+            app_chunk=opts.app_chunk, use_pallas=(eng == "pallas"),
+            tile_apps=opts.tile_apps,
+            padded=padded, devices=opts.devices)
+        fill(hybrid_idx, out)
     if spes_idx:
         # Like the fixed family: no per-bin state, and the float64 fused
-        # scan is already oracle-exact, so "pallas"/"reference" alias it.
+        # scan is already oracle-exact, so "pallas" aliases it.
         out = _run_spes_sweep(
             trace, [specs[s].to_config() for s in spes_idx],
             opts.include_trailing, app_chunk=opts.app_chunk,

@@ -51,8 +51,7 @@ oracle):
   * The *time layer* (inter-arrival times, waste accumulation) stays in the
     engine's dtype. The Pallas kernel receives its idle times already
     differenced and binned in float64 on the host (``encode_gaps``, a
-    float32 bracket for the verdict); the reference engine rebases each
-    app's clock (see ``simulator._gap_columns`` / ``_rebase_chunk``).
+    float32 bracket for the verdict; see ``simulator._gap_columns``).
   * Integer state must stay below 2**24 for the float32 casts to be exact
     and below 2**31 / PCT_SCALE for the scaled threshold compare; both hold
     for any trace this repo produces (per-app event counts are bounded by
@@ -127,7 +126,6 @@ __all__ = [
     "HybridStepConfig",
     "HybridSweepBlock",
     "SweepIdentities",
-    "fused_hybrid_step_math",
     "hybrid_gap_step_math",
     "hybrid_sweep_decide",
     "fused_hybrid_sweep_step_math",
@@ -700,7 +698,7 @@ def fused_spes_step_math(t_now, prev_t, mean, var, n_obs, load_at,
     previously decided bounds, the EW forecast-state update, and the
     banded window decision for the next gap.
 
-    Mirrors :func:`fused_hybrid_step_math`'s carry discipline: residency
+    Mirrors :func:`hybrid_gap_step_math`'s carry discipline: residency
     *bounds* are carried in the engine's time dtype, the forecast state
     stays float32, and the shared clock/observation count are
     config-independent (``mean``/``var``/bounds broadcast against a
@@ -779,46 +777,27 @@ class HybridStepConfig(NamedTuple):
             standard_keep=np.float32(standard_keep))
 
 
-def fused_hybrid_step_math(t_now, prev_t, cum, oob, cv_sum, cv_sum_sq,
-                           prewarm, unload_at, cold, waste, *,
-                           cfg: HybridStepConfig, gather: bool,
-                           bin_axis: int = -1):
-    """One fused hybrid-policy step: warm/cold + waste verdict under the
-    previously decided windows, histogram suffix-add update, Welford CV
-    accumulation, and the percentile-window decision for the next gap.
-
-    Carries (prewarm, unload_at) residency *bounds* — not (prewarm, keep)
-    — so no engine ever re-derives ``prewarm + keep`` in its own dtype.
-    Works identically inside ``lax.scan`` bodies (``gather=True``) and
-    Pallas kernel bodies (``gather=False``); the time dtype (float64 on
-    CPU, float32 on TPU) is taken from ``t_now``. ``cfg`` leaves may be
-    static scalars or traced values (per-config SMEM scalars on TPU).
-    ``bin_axis`` names ``cum``'s bin axis for the ``gather=False`` forms:
-    the Pallas kernel keeps apps on lanes (``cum`` [n_bins, TA], per-app
-    state [1, TA], ``bin_axis=0``).
-    """
-    valid = jnp.isfinite(t_now)
-    first = ~jnp.isfinite(prev_t)
-    it = t_now - prev_t
-    safe, in_b, oob_hit = classify_idle_time(it, valid & ~first,
-                                             cfg.bin_minutes, cfg.n_bins)
-    out = hybrid_gap_step_math(
-        it, it, (valid, first, safe, in_b, oob_hit), cum, oob, cv_sum,
-        cv_sum_sq, prewarm, unload_at, cold, waste, cfg=cfg, gather=gather,
-        bin_axis=bin_axis)
-    return (jnp.where(valid, t_now, prev_t),) + out
-
-
 def hybrid_gap_step_math(it_lo, it_hi, gap, cum, oob, cv_sum, cv_sum_sq,
                          prewarm, unload_at, cold, waste, *,
                          cfg: HybridStepConfig, gather: bool,
                          bin_axis: int = -1):
-    """:func:`fused_hybrid_step_math` from a classified gap: ``gap`` is the
-    (valid, first, safe_bin, in_bounds, oob_hit) of the event (from
-    ``classify_idle_time``, or ``decode_gaps`` of host-made codes) and
-    ``it_lo <= it <= it_hi`` brackets its idle time (equal when the
-    engine's time dtype holds it; see :func:`warm_from_bounds`). Returns
-    the step's state without the clock."""
+    """One fused hybrid-policy step from a classified gap: warm/cold +
+    waste verdict under the previously decided windows, histogram
+    suffix-add update, Welford CV accumulation, and the percentile-window
+    decision for the next gap.
+
+    ``gap`` is the (valid, first, safe_bin, in_bounds, oob_hit) of the
+    event (``decode_gaps`` of host-made codes) and ``it_lo <= it <= it_hi``
+    brackets its idle time (equal when the engine's time dtype holds it;
+    see :func:`warm_from_bounds`). Carries (prewarm, unload_at) residency
+    *bounds* — not (prewarm, keep) — so no engine ever re-derives
+    ``prewarm + keep`` in its own dtype. Works identically inside
+    ``lax.scan`` bodies (``gather=True``) and Pallas kernel bodies
+    (``gather=False``); ``cfg`` leaves may be static scalars or traced
+    values (per-config SMEM scalars on TPU). ``bin_axis`` names ``cum``'s
+    bin axis for the ``gather=False`` forms: the Pallas kernel keeps apps
+    on lanes (``cum`` [n_bins, TA], per-app state [1, TA], ``bin_axis=0``).
+    Returns the step's state; the clock stays with the caller."""
     valid, first, safe, in_b, oob_hit = gap
     wdtype = it_lo.dtype
 
@@ -1008,7 +987,7 @@ def fused_hybrid_sweep_step_math(t_now, prev_t, gcum, goob, gcv_sum,
     The bounds are CARRIED, not recomputed at step start: the step
     verdicts the closing gap under them, updates the group state, then
     re-decides from the post-update state — the same carried-windows
-    dataflow as the single-config :func:`fused_hybrid_step_math`, which
+    dataflow as the single-config :func:`hybrid_gap_step_math`, which
     lets XLA fuse the decision into the step that produced its state
     instead of stranding it on the next verdict's critical path (this is
     what restores the pre-sweep engine's S=1 step throughput, ROADMAP's

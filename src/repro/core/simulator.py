@@ -15,7 +15,7 @@ single-source policy math in :mod:`repro.core.policy_math`:
     :func:`_run_hybrid_sweep`): all apps advance together through a
     ``lax.scan`` over padded event indices, and S stacked policy
     configurations advance together along a *traced config axis* — the
-    trace is bucketed, chunked, rebased and scanned ONCE for the whole
+    trace is bucketed, chunked and scanned ONCE for the whole
     grid. The hybrid scan is factored (see
     :class:`repro.core.policy_math.HybridSweepBlock`): histogram
     sufficient statistics are carried once per distinct histogram shape,
@@ -36,13 +36,8 @@ single-source policy math in :mod:`repro.core.policy_math`:
     via scalar prefetch; ``engine="pallas"`` exercises it in interpret
     mode elsewhere (decided from the backend at call time).
 
-  * ``simulate_hybrid_batch_reference`` — the pre-fused batched engine
-    (per-step full-matrix cumsum), kept as the regression baseline for the
-    ``benchmarks/policy_overhead.py`` step-throughput comparison
-    (``engine="reference"``).
-
-Float32 exactness (the TPU story): TPUs have no float64, so the Pallas and
-reference engines carry float32 state. Absolute timestamps on a multi-week
+Float32 exactness (the TPU story): TPUs have no float64, so the Pallas
+engine carries float32 state. Absolute timestamps on a multi-week
 trace (t ~ 2e4 minutes) cannot hold sub-minute inter-arrival structure in
 float32. The Pallas driver therefore never sends the kernel a clock: on the
 host it differences each app's events in float64 (the oracle's own
@@ -52,12 +47,10 @@ the bin codes plus the idle time rounded down and up to float32
 rounded-down idle time with the load bound and the rounded-up one with the
 unload bound gives the oracle's warm verdict exactly; cold counts, windows
 and histograms match the oracle bit for bit, and waste is within one float32
-ulp per gap. The reference engine instead *rebases* each app row by its
-first event (float64, on the host) before the float32 cast, which is exact
-whenever the rebased times are float32-representable. Trailing waste is
-reconstructed afterward in float64 from the trace's clock. The decision
-layer itself (percentile thresholds, windows, CV gate) is dtype-invariant
-by construction — see :mod:`repro.core.policy_math`.
+ulp per gap. Trailing waste is reconstructed afterward in float64 from
+the trace's clock. The decision layer itself (percentile thresholds,
+windows, CV gate) is dtype-invariant by construction — see
+:mod:`repro.core.policy_math`.
 
 Exactly as in the paper, function execution time is simulated as 0 (so idle
 time == inter-arrival time) to account wasted memory time conservatively, and
@@ -82,7 +75,6 @@ import numpy as np
 
 from .. import telemetry
 from . import policy_math
-from .histogram import HistogramConfig
 from .policy import (FixedKeepAlivePolicy, HybridConfig, HybridHistogramPolicy,
                      Policy, is_warm, loaded_idle_time)
 from .workload import Trace
@@ -307,9 +299,8 @@ def _spes_scan(times, knobs: policy_math.SpesStepConfig):
     retraces). The forecast state is float32 regardless of the time dtype
     (see ``policy_math.spes_update``); the clock and observation count are
     config-independent. Trailing waste is left to the host
-    (``_absolute_results``), so the float32 rebased path shares this
-    program. Returns (cold [S,n], waste [S,n], last_t [n], load [S,n],
-    unload [S,n]).
+    (``_absolute_results``). Returns (cold [S,n], waste [S,n], last_t [n],
+    load [S,n], unload [S,n]).
     """
     n = times.shape[0]
     S = knobs.alpha.shape[0]
@@ -352,9 +343,8 @@ def _run_spes_sweep(trace: Trace, cfgs, include_trailing: bool = True, *,
     Always the float64 fused path (under x64): like the fixed family, this
     family has no per-bin state, and the float32 decision layer
     (``policy_math.spes_update`` rounds once from a float64 computation)
-    makes the scan oracle-exact — so the "pallas"/"reference" engines
-    alias it. ``devices`` shards each chunk's app rows like the other
-    sweep engines.
+    makes the scan oracle-exact — so the "pallas" engine aliases it.
+    ``devices`` shards each chunk's app rows like the other sweep engines.
     """
     from ..distributed import scaleout
     times, counts = padded if padded is not None else trace.to_padded()
@@ -678,20 +668,6 @@ def _hybrid_sweep_scan_pallas_sharded(gaps, cfg_i32, cfg_f32, n_bins: int,
         gaps, cfg_i32, cfg_f32)
 
 
-def _rebase_chunk(sub: np.ndarray):
-    """Per-chunk time rebasing for the float32 reference engine.
-
-    Shifts each app's timestamps by its own first event (the chunk's
-    row-wise minimum — times are sorted), in float64 on the host, BEFORE the
-    cast to float32. Policy verdicts depend only on inter-arrival times, so
-    the shift changes nothing semantically while keeping multi-week clocks
-    small enough for float32 to hold the fine IAT structure. Padding (+inf)
-    is unaffected. Returns (rebased float64 array, per-app offsets).
-    """
-    t0 = sub[:, 0].astype(np.float64)
-    return sub.astype(np.float64) - t0[:, None], t0
-
-
 @functools.cache
 def _gap_pool() -> ThreadPoolExecutor:
     """The host threads that build gap columns, one per usable core. Made
@@ -811,11 +787,11 @@ def _window_sequences_scan(gaps, cfg: policy_math.HybridStepConfig):
 
 def _absolute_results(waste, last_t, prewarm, unload_at, t0, duration,
                       include_trailing):
-    """Reconstruct absolute-time results after a (possibly rebased) scan.
+    """Reconstruct absolute-time results after a scan.
 
-    Trailing waste is computed on the host in float64 from the un-rebased
-    last-event clock, so the float32 engines never difference the large
-    absolute timestamps. Works for [n] rows and stacked [S, n] sweeps
+    Trailing waste is computed on the host in float64 from the last-event
+    clock (offset by ``t0``), so the float32 engine never differences the
+    large absolute timestamps. Works for [n] rows and stacked [S, n] sweeps
     (``last_t``/``t0`` broadcast along the config axis). Returns
     (waste64, prewarm64, keep64).
     """
@@ -834,7 +810,7 @@ def _run_hybrid_sweep(trace: Trace, hybrids: Sequence[HybridConfig],
                       use_pallas: Optional[bool] = None,
                       tile_apps: int = 1024,
                       padded=None, devices=None) -> dict:
-    """S hybrid configs over one bucketed/chunked/rebased trace pass.
+    """S hybrid configs over one bucketed/chunked trace pass.
 
     Configs are banded by bin count (so no config pays for another's wider
     histogram), but the trace preparation (``padded`` arrives precomputed
@@ -1038,125 +1014,6 @@ def _scan_extent(n: int, ndev: int, width: int, n_bins: int,
     return -(-per // ta) * ta * ndev, -(-width // tb) * tb
 
 
-# -- pre-sweep batched engine (benchmark/regression baseline) ----------------
-
-
-def _hybrid_step_reference(cfg: HistogramConfig, hybrid: HybridConfig, carry,
-                           t_now):
-    """Legacy fused step: raw counts + a full [n_apps, n_bins] cumsum and
-    percentile search per scan step — the step-throughput baseline the
-    incremental cumulative-count engine is benchmarked against. Decision
-    math is the same single-source helpers as every other engine."""
-    (prev_t, counts, total, oob, cv_sum, cv_sum_sq, prewarm, unload_at,
-     cold, waste) = carry
-    valid = jnp.isfinite(t_now)
-    first = ~jnp.isfinite(prev_t)
-    it = t_now - prev_t
-
-    warm = policy_math.warm_from_bounds(it, prewarm, unload_at)
-    is_cold = valid & (first | ~warm)
-    gap_waste = jnp.where(valid & ~first,
-                          policy_math.idle_from_bounds(it, prewarm, unload_at),
-                          0.0)
-
-    rec = valid & ~first
-    safe, in_b, oob_hit = policy_math.classify_idle_time(
-        it, rec, cfg.bin_minutes, cfg.n_bins)
-    rows = jnp.arange(counts.shape[0])
-    old = counts[rows, safe]
-    counts = counts.at[rows, safe].add(in_b.astype(jnp.int32))
-    total = total + in_b.astype(jnp.int32)
-    oob = oob + oob_hit.astype(jnp.int32)
-    cv_sum, cv_sum_sq = policy_math.welford_update(cv_sum, cv_sum_sq, in_b,
-                                                   old)
-
-    cum = jnp.cumsum(counts, axis=-1)   # the per-step recompute (baseline)
-    # masked-reduction search: the same one-sweep structure as the legacy
-    # argmax (the binary-search form would distort the baseline's cost)
-    head_bin = policy_math.first_bin_ge_scaled(
-        cum, policy_math.percentile_threshold_scaled(
-            total, cfg.head_percentile), gather=False)
-    tail_bin = policy_math.first_bin_ge_scaled(
-        cum, policy_math.percentile_threshold_scaled(
-            total, cfg.tail_percentile), gather=False) + 1
-    new_load, new_unload = policy_math.window_values(
-        head_bin, tail_bin, cfg.bin_minutes, cfg.range_minutes, cfg.margin)
-    use_hist = policy_math.use_histogram_gate(
-        total, oob, cv_sum, cv_sum_sq, cfg.n_bins, hybrid.min_samples,
-        policy_math.cv_gate_factor(hybrid.cv_threshold),
-        hybrid.oob_fraction_threshold)
-    std_load, std_unload = policy_math.standard_window_bounds(
-        hybrid.standard_keep_alive)
-    new_load = jnp.where(use_hist, new_load, std_load)
-    new_unload = jnp.where(use_hist, new_unload, std_unload)
-
-    prewarm = jnp.where(valid, new_load, prewarm)
-    unload_at = jnp.where(valid, new_unload, unload_at)
-    prev_t = jnp.where(valid, t_now, prev_t)
-    return (prev_t, counts, total, oob, cv_sum, cv_sum_sq, prewarm, unload_at,
-            cold + is_cold, waste + gap_waste), None
-
-
-@partial(jax.jit, static_argnums=(1, 2))
-def _hybrid_scan_reference(times, cfg: HistogramConfig, hybrid: HybridConfig):
-    n = times.shape[0]
-    n_bins = cfg.n_bins
-    init = (
-        jnp.full((n,), -jnp.inf, jnp.float32),
-        jnp.zeros((n, n_bins), jnp.int32),
-        jnp.zeros((n,), jnp.int32),
-        jnp.zeros((n,), jnp.int32),
-        jnp.zeros((n,), jnp.float32),
-        jnp.zeros((n,), jnp.float32),
-        jnp.zeros((n,), jnp.float32),                                 # prewarm
-        jnp.full((n,), jnp.float32(hybrid.standard_keep_alive)),      # unload_at
-        jnp.zeros((n,), jnp.int32),
-        jnp.zeros((n,), jnp.float32),
-    )
-    carry, _ = jax.lax.scan(partial(_hybrid_step_reference, cfg, hybrid),
-                            init, times.T)
-    (last_t, counts, total, oob, _, _, prewarm, unload_at, cold, waste) = carry
-    return cold, waste, oob > 0, last_t, prewarm, unload_at
-
-
-def _simulate_hybrid_batch_reference(trace: Trace, hybrid: HybridConfig,
-                                     include_trailing: bool = True,
-                                     padded=None) -> SimResult:
-    """Pre-sweep batched hybrid engine (float32, per-step cumsum recompute,
-    per-chunk time rebasing)."""
-    times, counts = padded if padded is not None else trace.to_padded()
-    n = trace.n_apps
-    cold_parts = np.zeros(n, np.int64)
-    waste_parts = np.zeros(n, np.float64)
-    pre_parts = np.zeros(n, np.float64)
-    keep_parts = np.full(n, hybrid.standard_keep_alive, np.float64)
-    oob_flags = np.zeros(n, bool)
-    duration = float(trace.duration_minutes)
-    for sel, sub in _buckets(times, counts):
-        _check_scan_width(sub.shape[1])
-        sub, t0 = _rebase_chunk(sub)
-        cold, waste, oobh, last_t, prewarm, unload_at = \
-            _hybrid_scan_reference(jnp.asarray(sub, jnp.float32),
-                                   hybrid.histogram, hybrid)
-        cold_parts[sel] = np.asarray(cold)
-        oob_flags[sel] = np.asarray(oobh)
-        waste_parts[sel], pre_parts[sel], keep_parts[sel] = \
-            _absolute_results(waste, last_t, prewarm, unload_at, t0,
-                              duration, include_trailing)
-    result = SimResult(cold_parts, counts.astype(np.int64), waste_parts,
-                       pre_parts, keep_parts)
-    if hybrid.use_arima and oob_flags.any():
-        from ..forecast.replay import replay_oob_apps
-        arima_idx = np.where(oob_flags)[0]
-        out = replay_oob_apps(times, counts, duration, hybrid, arima_idx,
-                              include_trailing)
-        result.cold[arima_idx] = out["cold"]
-        result.wasted_minutes[arima_idx] = out["wasted_minutes"]
-        result.final_prewarm[arima_idx] = out["final_prewarm"]
-        result.final_keep_alive[arima_idx] = out["final_keep_alive"]
-    return result
-
-
 # --------------------------------------------------------------------------
 # Removed entry points (deprecation cycle completed in PR 3 -> PR 5)
 # --------------------------------------------------------------------------
@@ -1165,7 +1022,7 @@ _REMOVED = {
     "simulate": "run(trace, spec)",
     "simulate_fixed_batch": "run(trace, FixedSpec(keep_alive))",
     "simulate_hybrid_batch": "run(trace, HybridSpec(...))",
-    "simulate_hybrid_batch_reference": 'run(trace, spec, engine="reference")',
+    "simulate_hybrid_batch_reference": "run(trace, spec)",
 }
 
 

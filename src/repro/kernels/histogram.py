@@ -1,39 +1,27 @@
-"""Fleet-scale hybrid-histogram policy update — Pallas TPU kernel.
+"""The hybrid-histogram policy's simulator scan — Pallas TPU kernel.
 
-This is the paper's own hot loop, adapted TPU-natively (DESIGN.md §2). The
-paper's challenges #4/#5 demand O(µs) policy updates per invocation; at
-provider scale (millions of app endpoints) the control plane batches the
-idle-time observations of one scheduling tick and updates *all* app
-histograms plus their policy windows in a single vectorized pass:
+This is the paper's own hot loop (§4.2), adapted TPU-natively: every
+invocation of every app updates its idle-time histogram, its CV
+statistics and its (prewarm, keep-alive) windows, and the previous
+windows give the event its warm/cold verdict and wasted memory time.
 
-  for each app a in tile:                      (one VMEM tile = TA apps)
-    counts[a, bin(it_a)] += 1                  (or OOB counter)
-    cv[a]     <- Welford O(1) update
-    head/tail <- weighted 5th/99th percentile over bins
-    prewarm/keepalive <- margins + representativeness fallback
+:func:`hybrid_sweep_scan_pallas` walks a chunk's event columns for S
+stacked policy configurations (grid (S, n_apps / TA, width / TB)): each
+(config, app tile)'s histogram state stays in VMEM while the kernel walks
+the time axis, and reaches HBM once. The per-config knobs arrive as a
+scalar-prefetched SMEM config block, so a new grid point is a new SMEM
+row, not a recompile. The event columns are made on the host from the
+float64 clock (``simulator._gap_columns``), so the kernel never sees a
+clock.
 
-Everything is rank-2 [TA, n_bins] arithmetic — ideal VPU work. The decision
-formulas are NOT written here: kernel bodies call the single-source helpers
-in :mod:`repro.core.policy_math` with ``gather=False`` (masked-reduction
-forms — compare-against-iota instead of row gathers), which trace inside
-Pallas identically to the ``lax.scan`` engines.
-
-Two kernels:
-
-  * :func:`policy_update_pallas` — one scheduling tick of the control
-    plane (grid (n_apps / TA,), fully parallel over app tiles);
-  * :func:`hybrid_sweep_scan_pallas` — the simulator's whole scan over a
-    chunk's event columns for S stacked policy configurations (grid
-    (S, n_apps / TA, width / TB)): each (config, app tile)'s histogram
-    state stays in VMEM while the kernel walks the time axis, and reaches
-    HBM once. The per-config knobs arrive as a scalar-prefetched SMEM
-    config block, so a new grid point is a new SMEM row, not a recompile.
-    :func:`fused_hybrid_step_pallas` is its one-step, one-config call in
-    the row layout.
+Everything is [n_bins, TA] and [1, TA] arithmetic, apps on lanes — VPU
+work. The decision formulas are NOT written here: the kernel body calls
+the single-source step ``policy_math.hybrid_gap_step_math`` with
+``gather=False`` (masked-reduction forms — compare-against-iota instead
+of row gathers), which traces inside Pallas exactly as it does inside the
+``lax.scan`` engines.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -41,117 +29,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core import policy_math
-
-
-def _policy_kernel(counts_ref, oob_ref, total_ref, cvs_ref, cvss_ref,
-                   bins_ref, active_ref,
-                   ncounts_ref, noob_ref, ntotal_ref, ncvs_ref, ncvss_ref,
-                   prewarm_ref, keep_ref, use_hist_ref, *,
-                   n_bins: int, head_pct: float, tail_pct: float,
-                   margin: float, bin_minutes: float, range_minutes: float,
-                   cv_threshold: float, min_samples: int, oob_threshold: float):
-    counts = counts_ref[...]                       # [TA, n_bins] i32
-    bins = bins_ref[...]                           # [TA] i32 (bin idx; >=n_bins -> OOB)
-    active = active_ref[...] != 0                  # [TA]
-    TA = counts.shape[0]
-
-    in_b = active & (bins >= 0) & (bins < n_bins)
-    oob_hit = active & (bins >= n_bins)
-    safe = jnp.clip(bins, 0, n_bins - 1)
-
-    iota = jax.lax.broadcasted_iota(jnp.int32, (TA, n_bins), 1)
-    onehot = (iota == safe[:, None]) & in_b[:, None]
-    old = jnp.sum(jnp.where(onehot, counts, 0), axis=1)          # [TA]
-    new_counts = counts + onehot.astype(jnp.int32)
-
-    total = total_ref[...] + in_b.astype(jnp.int32)
-    oob = oob_ref[...] + oob_hit.astype(jnp.int32)
-    cvs, cvss = policy_math.welford_update(cvs_ref[...], cvss_ref[...],
-                                           in_b, old)
-
-    cum = jnp.cumsum(new_counts, axis=1)                          # [TA, n_bins]
-    head_bin = policy_math.first_bin_ge_scaled(
-        cum, policy_math.percentile_threshold_scaled(total, head_pct),
-        gather=False)
-    tail_bin = policy_math.first_bin_ge_scaled(
-        cum, policy_math.percentile_threshold_scaled(total, tail_pct),
-        gather=False) + 1
-    load_at, unload_at = policy_math.window_values(
-        head_bin, tail_bin, bin_minutes, range_minutes, margin)
-    use_hist = policy_math.use_histogram_gate(
-        total, oob, cvs, cvss, n_bins, min_samples,
-        policy_math.cv_gate_factor(cv_threshold), oob_threshold)
-    std_load, std_unload = policy_math.standard_window_bounds(range_minutes)
-    prewarm = jnp.where(use_hist, load_at, std_load)
-    keep = jnp.where(use_hist, unload_at, std_unload) - prewarm
-
-    ncounts_ref[...] = new_counts
-    noob_ref[...] = oob
-    ntotal_ref[...] = total
-    ncvs_ref[...] = cvs
-    ncvss_ref[...] = cvss
-    prewarm_ref[...] = prewarm
-    keep_ref[...] = keep
-    use_hist_ref[...] = use_hist.astype(jnp.int32)
-
-
-def policy_update_pallas(counts, oob, total, cv_sum, cv_sum_sq, bins, active,
-                         *, head_pct=5.0, tail_pct=99.0, margin=0.10,
-                         bin_minutes=1.0, range_minutes=240.0,
-                         cv_threshold=2.0, min_samples=5, oob_threshold=0.5,
-                         tile_apps: int = 512, interpret: bool = True):
-    """Batched histogram+policy update for the whole fleet.
-
-    counts: [n_apps, n_bins] i32; oob/total: [n_apps] i32;
-    cv_sum/cv_sum_sq: [n_apps] f32; bins: [n_apps] i32 (this tick's IT bin,
-    >= n_bins means OOB); active: [n_apps] i32 (0/1).
-    Returns (new_counts, new_oob, new_total, new_cv_sum, new_cv_sum_sq,
-             prewarm, keep_alive, use_hist).
-    """
-    n_apps, n_bins = counts.shape
-    TA = min(tile_apps, n_apps)
-    pad = (-n_apps) % TA
-    if pad:
-        # pad with inactive rows so the app tiling covers every app
-        pv = lambda x, fill=0: jnp.concatenate(
-            [x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)])
-        counts, oob, total = pv(counts), pv(oob), pv(total)
-        cv_sum, cv_sum_sq = pv(cv_sum), pv(cv_sum_sq)
-        bins, active = pv(bins), pv(active)
-        n_apps += pad
-    grid = (n_apps // TA,)
-    kernel = functools.partial(
-        _policy_kernel, n_bins=n_bins, head_pct=head_pct, tail_pct=tail_pct,
-        margin=margin, bin_minutes=bin_minutes, range_minutes=range_minutes,
-        cv_threshold=cv_threshold, min_samples=min_samples,
-        oob_threshold=oob_threshold)
-
-    vec = lambda dt: pl.BlockSpec((TA,), lambda i: (i,))
-    mat = pl.BlockSpec((TA, n_bins), lambda i: (i, 0))
-    outs = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[mat, vec(None), vec(None), vec(None), vec(None), vec(None),
-                  vec(None)],
-        out_specs=[mat, vec(None), vec(None), vec(None), vec(None), vec(None),
-                   vec(None), vec(None)],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_apps, n_bins), jnp.int32),
-            jax.ShapeDtypeStruct((n_apps,), jnp.int32),
-            jax.ShapeDtypeStruct((n_apps,), jnp.int32),
-            jax.ShapeDtypeStruct((n_apps,), jnp.float32),
-            jax.ShapeDtypeStruct((n_apps,), jnp.float32),
-            jax.ShapeDtypeStruct((n_apps,), jnp.float32),
-            jax.ShapeDtypeStruct((n_apps,), jnp.float32),
-            jax.ShapeDtypeStruct((n_apps,), jnp.int32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
-        interpret=interpret,
-    )(counts, oob, total, cv_sum, cv_sum_sq, bins, active)
-    if pad:
-        outs = tuple(o[:-pad] for o in outs)
-    return outs
 
 
 # ---------------------------------------------------------------------------
@@ -176,8 +53,8 @@ CFG_F32_COLS = ("margin_lo", "margin_hi", "bin_minutes", "range_f32",
 # temporaries (several copies of ``cum``) fit beside it.
 _SCAN_VMEM_BYTES = 8 << 20
 # The most vregs a tile's ``cum`` may span: 60 bins x 1,024 apps packed
-# (60) and 240 bins x 512 lane-only (120) are the tiles checked on a v5e
-# against the per-step kernel it replaced, bit for bit.
+# (60) and 240 bins x 512 lane-only (120) are the largest tiles run
+# bit-exact on a v5e.
 _MAX_TILE_VREGS = 128
 _N_COLUMNS = 3            # it_lo, it_hi, code
 _N_VECTORS = 7            # the per-app state vectors beside ``cum``
@@ -362,49 +239,3 @@ def hybrid_sweep_scan_pallas(it_lo, it_hi, code, state, cfg_i32, cfg_f32, *,
     if pad_apps:
         outs = tuple(o[..., :n_apps] for o in outs)
     return tuple(outs)
-
-
-def fused_hybrid_step_pallas(t_now, prev_t, cum, oob, cv_sum, cv_sum_sq,
-                             prewarm, unload_at, cold, waste, *,
-                             head_pct=5.0, tail_pct=99.0, margin=0.10,
-                             bin_minutes=1.0, range_minutes=240.0,
-                             cv_threshold=2.0, min_samples=5,
-                             oob_threshold=0.5, standard_keep=240.0,
-                             tile_apps: int = 1024, interpret: bool = False):
-    """Single-config fused step: a one-column, one-config call of the scan
-    kernel.
-
-    Kept as the scalar-parity/benchmark surface (``ops.fused_hybrid_step``)
-    in the row layout (``cum`` [n_apps, n_bins], per-app state [n_apps]);
-    the knobs are packed into a one-row SMEM config block exactly as the
-    sweep driver would (``HybridStepConfig.from_host`` owns the rounding).
-    """
-    n_apps, n_bins = cum.shape
-    if n_apps == 0:
-        return (prev_t, cum, oob, cv_sum, cv_sum_sq, prewarm, unload_at,
-                cold, waste)
-    c = policy_math.HybridStepConfig.from_host(
-        n_bins=n_bins, head_pct=head_pct, tail_pct=tail_pct, margin=margin,
-        bin_minutes=bin_minutes, range_minutes=range_minutes,
-        cv_threshold=cv_threshold, min_samples=min_samples,
-        oob_threshold=oob_threshold, standard_keep=standard_keep)
-    cfg_i32 = jnp.asarray(
-        [[c.n_bins, c.head_numer, c.tail_numer, c.min_samples]], jnp.int32)
-    cfg_f32 = jnp.asarray(
-        [[c.margin_lo, c.margin_hi, c.bin_f32, c.range_f32, c.cv_factor,
-          c.oob_threshold, c.standard_keep]], jnp.float32)
-    # The row layout's clock stays outside the kernel: classify the gap in
-    # float32 here, exactly as the sweep step would.
-    valid = jnp.isfinite(t_now)
-    first = ~jnp.isfinite(prev_t)
-    it = t_now - prev_t
-    code = policy_math.encode_gaps(it, valid, first, c.bin_f32, n_bins)
-    lane = lambda v: v[None, None]                 # [n] -> [1, 1, n]
-    state = (cum.T[None],) + tuple(
-        lane(v) for v in (oob, cv_sum, cv_sum_sq, prewarm, unload_at, cold,
-                          waste))
-    outs = hybrid_sweep_scan_pallas(
-        it[None], it[None], code[None], state, cfg_i32, cfg_f32,
-        tile_apps=tile_apps, interpret=interpret)
-    return (jnp.where(valid, t_now, prev_t),) + tuple(
-        o[0].T if k == 0 else o[0, 0] for k, o in enumerate(outs))

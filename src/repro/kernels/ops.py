@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from . import interpret_mode
 from .decode_attention import flash_decode
 from .flash_attention import flash_attention_bhsd
-from .histogram import fused_hybrid_step_pallas, policy_update_pallas
 from .rglru_scan import rglru_scan_pallas
 from .ssd_scan import ssd_scan_pallas
 
@@ -56,25 +55,3 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 256):
 def rglru_scan(b_in, a, *, block_t: int = 256, block_d: int = 512):
     return rglru_scan_pallas(b_in, a, block_t=block_t, block_d=block_d,
                              interpret=interpret_mode())
-
-
-@partial(jax.jit, static_argnames=("head_pct", "tail_pct", "margin",
-                                   "bin_minutes", "range_minutes",
-                                   "cv_threshold", "min_samples",
-                                   "oob_threshold", "tile_apps"))
-def policy_update(counts, oob, total, cv_sum, cv_sum_sq, bins, active, **kw):
-    return policy_update_pallas(counts, oob, total, cv_sum, cv_sum_sq, bins,
-                                active, interpret=interpret_mode(), **kw)
-
-
-@partial(jax.jit, static_argnames=("head_pct", "tail_pct", "margin",
-                                   "bin_minutes", "range_minutes",
-                                   "cv_threshold", "min_samples",
-                                   "oob_threshold", "standard_keep",
-                                   "tile_apps"))
-def fused_hybrid_step(t_now, prev_t, cum, oob, cv_sum, cv_sum_sq, prewarm,
-                      unload_at, cold, waste, **kw):
-    """Fused simulator step (see kernels.histogram.fused_hybrid_step_pallas)."""
-    return fused_hybrid_step_pallas(t_now, prev_t, cum, oob, cv_sum,
-                                    cv_sum_sq, prewarm, unload_at, cold,
-                                    waste, interpret=interpret_mode(), **kw)

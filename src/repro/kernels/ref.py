@@ -39,43 +39,3 @@ def ssd_ref(x, dt, A, B, C, chunk):
 
 def rglru_ref(b_in, a):
     return rglru_scan_ref(b_in, a)
-
-
-def policy_update_ref(counts, oob, total, cv_sum, cv_sum_sq, bins, active,
-                      *, head_pct=5.0, tail_pct=99.0, margin=0.10,
-                      bin_minutes=1.0, range_minutes=240.0, cv_threshold=2.0,
-                      min_samples=5, oob_threshold=0.5):
-    """Vectorized jnp oracle: same single-source policy math as the kernel,
-    but through the XLA-friendly gather forms."""
-    from ..core import policy_math
-
-    n_apps, n_bins = counts.shape
-    active = active != 0
-    in_b = active & (bins >= 0) & (bins < n_bins)
-    oob_hit = active & (bins >= n_bins)
-    safe = jnp.clip(bins, 0, n_bins - 1)
-    onehot = jax.nn.one_hot(safe, n_bins, dtype=jnp.int32) * in_b[:, None]
-    old = jnp.take_along_axis(counts, safe[:, None], axis=1)[:, 0]
-    new_counts = counts + onehot
-    total = total + in_b.astype(jnp.int32)
-    oob = oob + oob_hit.astype(jnp.int32)
-    cv_sum, cv_sum_sq = policy_math.welford_update(cv_sum, cv_sum_sq, in_b,
-                                                   old)
-
-    cum = jnp.cumsum(new_counts, axis=1)
-    head_bin = policy_math.first_bin_ge_scaled(
-        cum, policy_math.percentile_threshold_scaled(total, head_pct),
-        gather=True)
-    tail_bin = policy_math.first_bin_ge_scaled(
-        cum, policy_math.percentile_threshold_scaled(total, tail_pct),
-        gather=True) + 1
-    load_at, unload_at = policy_math.window_values(
-        head_bin, tail_bin, bin_minutes, range_minutes, margin)
-    use_hist = policy_math.use_histogram_gate(
-        total, oob, cv_sum, cv_sum_sq, n_bins, min_samples,
-        policy_math.cv_gate_factor(cv_threshold), oob_threshold)
-    std_load, std_unload = policy_math.standard_window_bounds(range_minutes)
-    prewarm = jnp.where(use_hist, load_at, std_load)
-    keep = jnp.where(use_hist, unload_at, std_unload) - prewarm
-    return (new_counts, oob, total, cv_sum, cv_sum_sq, prewarm, keep,
-            use_hist.astype(jnp.int32))

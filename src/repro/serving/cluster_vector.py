@@ -17,7 +17,7 @@ columnar :class:`repro.serving.apptable.AppTable` in three array passes:
      gap columns; the device sees int32/float32 only) yields every per-gap
      residency bound up front. Apps whose out-of-bounds counter ever
      trips the ARIMA gate are recomputed through the scalar policy (same
-     post-pass idiom as ``simulator._simulate_hybrid_batch_reference``).
+     post-pass idiom as ``simulator._run_hybrid_sweep``).
 
   C. **Gap replay.** With windows known, each inter-arrival gap closes in
      closed form: keep-alive expiries and pre-warm fires happen at the

@@ -2,8 +2,8 @@
 golden-trace regression tests, and ``scripts/regen_golden.py``.
 
 Each constructor documents which float32-exactness regime it exercises:
-the batched float32 engines (Pallas / reference) rebase every app by its
-first event, so they reproduce the float64 oracle bit-for-bit whenever the
+the batched float32 engine (Pallas) is fed every app's gaps differenced on
+the host in float64, so its idle times are exact whenever the per-app
 *rebased* times are float32-representable — which each constructor
 guarantees by keeping times on a dyadic grid with a bounded significand.
 """

@@ -47,20 +47,25 @@ GRID = [
 
 OPTS = EngineOptions(app_chunk=11)   # ragged chunks on purpose
 
+# (engine, options): every engine at OPTS, and the Pallas engine at the
+# default options too (each bucket one chunk, as in every chip cell).
+ENGINE_CASES = [pytest.param(e, OPTS, id=e) for e in sorted(ENGINES)] + [
+    pytest.param("pallas", EngineOptions(), id="pallas-one-chunk")]
+
 
 @pytest.fixture(scope="module")
 def trace():
     return coarse_twoweek()
 
 
-@pytest.mark.parametrize("engine", sorted(ENGINES))
-def test_sweep_rows_equal_single_config_runs(trace, engine):
+@pytest.mark.parametrize("engine,opts", ENGINE_CASES)
+def test_sweep_rows_equal_single_config_runs(trace, engine, opts):
     """sweep() row s == run(spec_s) bit-for-bit, per engine — including
     float32 waste, which accumulates in the same order either way."""
-    res = sweep(trace, GRID, engine=engine, options=OPTS)
+    res = sweep(trace, GRID, engine=engine, options=opts)
     assert len(res) == len(GRID)
     for s, spec in enumerate(GRID):
-        one = run(trace, spec, engine=engine, options=OPTS)
+        one = run(trace, spec, engine=engine, options=opts)
         err = f"engine={engine} row={s} ({spec.name})"
         np.testing.assert_array_equal(res.cold[s], one.cold, err_msg=err)
         np.testing.assert_array_equal(res.invocations, one.invocations,
@@ -73,11 +78,14 @@ def test_sweep_rows_equal_single_config_runs(trace, engine):
                                       one.final_keep_alive, err_msg=err)
 
 
-@pytest.mark.parametrize("engine", ["fused", "pallas", "reference"])
-def test_sweep_matches_scalar_oracle(trace, engine):
+@pytest.mark.parametrize("engine,opts", [
+    pytest.param("fused", OPTS, id="fused"),
+    pytest.param("pallas", OPTS, id="pallas"),
+    pytest.param("pallas", EngineOptions(), id="pallas-one-chunk")])
+def test_sweep_matches_scalar_oracle(trace, engine, opts):
     """Every sweep row reproduces the float64 scalar oracle exactly on the
     decision-layer outputs (cold counts, windows)."""
-    res = sweep(trace, GRID, engine=engine, options=OPTS)
+    res = sweep(trace, GRID, engine=engine, options=opts)
     for s, spec in enumerate(GRID):
         oracle = simulate_scalar(trace, spec.build())
         err = f"engine={engine} row={s} ({spec.name})"
@@ -147,6 +155,9 @@ def test_sweep_rejects_bad_inputs(trace):
         sweep(trace, [])
     with pytest.raises(ValueError, match="unknown engine"):
         sweep(trace, [FixedSpec(10.0)], engine="warp")
+    for gone in ("reference",):      # engines removed from ENGINES
+        with pytest.raises(ValueError, match="unknown engine"):
+            run(trace, HybridSpec.from_config(CFG48), engine=gone)
     with pytest.raises(TypeError, match="PolicySpec"):
         as_spec(object())
     with pytest.raises(TypeError, match="Trace or WorkloadSpec"):
@@ -162,19 +173,19 @@ def test_sweep_rejects_bad_inputs(trace):
 # --- the trace axis: sweep(traces=[...], specs=[...]) ------------------------
 
 
-@pytest.mark.parametrize("engine", sorted(ENGINES))
-def test_trace_axis_cells_equal_single_trace_runs(trace, engine):
+@pytest.mark.parametrize("engine,opts", ENGINE_CASES)
+def test_trace_axis_cells_equal_single_trace_runs(trace, engine, opts):
     """Every (t, s) cell of a trace x policy grid is bit-identical to the
     corresponding single-trace run() — the acceptance bar for the axis."""
     spec_b = bursty(24, days=3.0, seed=5, max_events=24, min_events=1)
     traces = [trace, spec_b]
-    grid = sweep(traces=traces, specs=GRID, engine=engine, options=OPTS)
+    grid = sweep(traces=traces, specs=GRID, engine=engine, options=opts)
     assert grid.shape == (2, len(GRID))
     assert len(list(iter(grid))) == 2
     materialized = [trace, spec_b.materialize()]
     for t, tr in enumerate(materialized):
         for s, spec in enumerate(GRID):
-            one = run(tr, spec, engine=engine, options=OPTS)
+            one = run(tr, spec, engine=engine, options=opts)
             err = f"engine={engine} t={t} s={s} ({spec.name})"
             cell = grid.row(t, s)
             np.testing.assert_array_equal(cell.cold, one.cold, err_msg=err)

@@ -125,8 +125,12 @@ def test_hybrid_arima_replay_matches_scalar_oracle(seed):
                       f"hybrid+arima fused chunked seed={seed}")
 
 
-@pytest.mark.parametrize("engine", ["fused", "pallas", "reference"])
-def test_forecaster_consulted_mid_trace_only_is_replayed(engine):
+@pytest.mark.parametrize("engine,opts", [
+    pytest.param("fused", {}, id="fused"),
+    pytest.param("pallas", {}, id="pallas"),
+    # each app its own chunk: the post-pass reads OOB flags across chunks
+    pytest.param("pallas", {"app_chunk": 1}, id="pallas-app_chunk1")])
+def test_forecaster_consulted_mid_trace_only_is_replayed(engine, opts):
     """An app that is OOB-heavy — and so consults the forecaster — early in
     its trace but not at its end must still be replayed: its final state
     alone does not show that the forecaster governed some of its gaps."""
@@ -141,8 +145,8 @@ def test_forecaster_consulted_mid_trace_only_is_replayed(engine):
     no_forecast = simulate_scalar(
         trace, HybridSpec(range_minutes=60.0, use_arima=False).build())
     assert oracle.cold[0] != no_forecast.cold[0]
-    got = run(trace, spec, engine=engine)
-    _assert_run_equal(got, oracle, f"mid-trace forecaster, {engine}",
+    got = run(trace, spec, engine=engine, options=EngineOptions(**opts))
+    _assert_run_equal(got, oracle, f"mid-trace forecaster, {engine} {opts}",
                       waste_exact=(engine == "fused"))
 
 
@@ -181,7 +185,7 @@ def spes_case(request):
 
 @pytest.mark.parametrize("engine,opts", [
     ("fused", {}), ("fused", {"app_chunk": 7}),
-    ("pallas", {}), ("reference", {}),
+    ("pallas", {}),
 ])
 def test_spes_engines_match_scalar_oracle(spes_case, engine, opts):
     """Cold counts, final windows AND waste bit-identical for every

@@ -12,7 +12,7 @@ import os
 import numpy as np
 import pytest
 
-from repro.core.experiment import HybridSpec, run
+from repro.core.experiment import EngineOptions, HybridSpec, run
 
 from golden_traces import GOLDEN_TRACES
 
@@ -26,8 +26,9 @@ ENGINES = {
                                   engine="fused"),
     "pallas_f32": lambda t, cfg: run(t, HybridSpec.from_config(cfg),
                                      engine="pallas"),
-    "reference_f32": lambda t, cfg: run(t, HybridSpec.from_config(cfg),
-                                        engine="reference"),
+    "pallas_f32_chunked": lambda t, cfg: run(
+        t, HybridSpec.from_config(cfg), engine="pallas",
+        options=EngineOptions(app_chunk=7)),
 }
 
 
@@ -59,7 +60,7 @@ def test_golden_trace(golden_case, engine):
                                   np.asarray(want["final_keep_alive"]),
                                   err_msg=err)
     # float64 engines reproduce the recorded waste exactly (JSON round-trips
-    # float64); float32 engines accumulate their gap terms in float32
+    # float64); the float32 engine accumulates its gap terms in float32
     tol = dict(rtol=0, atol=0) if engine in ("scalar", "jnp_f64") \
         else dict(rtol=1e-5, atol=1e-3)
     np.testing.assert_allclose(res.wasted_minutes,

@@ -1,67 +1,79 @@
-"""Parity suite: fused hybrid-step Pallas kernel vs the scalar reference.
+"""Parity suite: the Pallas sweep scan kernel vs the scalar reference.
 
-The fused kernel (repro.kernels.histogram.fused_hybrid_step_pallas) must
-reproduce, per event, exactly what the control-plane scalar path
-(AppHistogram + HybridHistogramPolicy decision tree) computes: histogram
-contents, OOB counters, and the (prewarm, keep-alive) windows. Property
-tests run when hypothesis is installed (see requirements-dev.txt); the
-seeded stream tests below always run.
+The kernel (``repro.kernels.histogram.hybrid_sweep_scan_pallas``), fed the
+event columns the sweep driver builds (``simulator._gap_columns``: every
+gap differenced and binned on the host in float64, its idle time sent as a
+float32 bracket), must reproduce, per event, exactly what the control-plane
+scalar path (AppHistogram + HybridHistogramPolicy decision tree) computes:
+histogram contents, OOB counters, and the (prewarm, keep-alive) windows.
+
+One kernel call covers a whole stream: lane i holds the stream's first
+i + 1 events, so its final state is the state after event i. Every case
+runs in both tile layouts of ``histogram.scan_blocks``: a lane-only tile
+(a chunk of fewer than 1,024 apps) and the packed 1,024-app tile of every
+chip chunk (the lanes padded to 1,024 with event-free rows). Property tests
+run when hypothesis is installed (see requirements-dev.txt); the seeded
+stream tests below always run.
 """
+import functools
+
 import numpy as np
 import pytest
+
+import jax
 import jax.numpy as jnp
 
 from repro.core.histogram import AppHistogram, HistogramConfig
 from repro.core.policy import HybridConfig, HybridHistogramPolicy
-from repro.kernels import ops
+from repro.core.simulator import _build_pallas_cfg, _gap_columns
+from repro.kernels.histogram import hybrid_sweep_scan_pallas, scan_blocks
+
+from test_sweep_scan_kernel import _init
 
 CFG = HistogramConfig(range_minutes=48.0)   # 48 bins: fast in interpret mode
 HYB = HybridConfig(histogram=CFG, use_arima=False)
-N_LANES = 5                                 # > tile to exercise padding
+MAX_EVENTS = 64         # the lanes of the lane-only layout: the longest stream
+TILE_APPS = 1024        # the driver's app tile
+LAYOUTS = {"lanes": MAX_EVENTS, "packed": TILE_APPS}   # layout -> lanes
+CFG_I32, CFG_F32 = _build_pallas_cfg([HYB])
+
+_scan = jax.jit(functools.partial(hybrid_sweep_scan_pallas,
+                                  tile_apps=TILE_APPS, interpret=True))
 
 
-def _kernel_stream(its, tile_apps=4):
-    """Drive the fused kernel one event at a time (N_LANES identical apps).
+def _kernel_stream(its, layout):
+    """Drive the scan kernel over every prefix of one stream at once.
 
-    Yields (prewarm, keep, total, oob, counts) after every event.
+    Returns (prewarm, keep, total, oob, counts) after every event.
     """
-    n_bins = CFG.n_bins
-    state = (
-        jnp.full((N_LANES,), -jnp.inf, jnp.float32),
-        jnp.zeros((N_LANES, n_bins), jnp.int32),
-        jnp.zeros((N_LANES,), jnp.int32),
-        jnp.zeros((N_LANES,), jnp.float32),
-        jnp.zeros((N_LANES,), jnp.float32),
-        jnp.zeros((N_LANES,), jnp.float32),
-        jnp.full((N_LANES,), jnp.float32(HYB.standard_keep_alive)),
-        jnp.zeros((N_LANES,), jnp.int32),
-        jnp.zeros((N_LANES,), jnp.float32),
-    )
-    t = 0.0
-    out = []
-    for it in its:
-        t += it
-        state = ops.fused_hybrid_step(
-            jnp.full((N_LANES,), t, jnp.float32), *state,
-            head_pct=CFG.head_percentile, tail_pct=CFG.tail_percentile,
-            margin=CFG.margin, bin_minutes=CFG.bin_minutes,
-            range_minutes=CFG.range_minutes, cv_threshold=HYB.cv_threshold,
-            min_samples=HYB.min_samples,
-            oob_threshold=HYB.oob_fraction_threshold,
-            standard_keep=HYB.standard_keep_alive, tile_apps=tile_apps)
-        (_, cum, oob, _, _, prewarm, unload_at, _, _) = state
-        counts = np.diff(np.concatenate(
-            [[0], np.asarray(cum[0], np.int64)]))
-        # the carry holds residency bounds; keep-alive is their exact
-        # float64 difference (same reconstruction the drivers use)
-        out.append((float(prewarm[0]), float(unload_at[0]) - float(prewarm[0]),
-                    int(cum[0, -1]), int(oob[0]), counts))
-        # all lanes (incl. the padded-tile ones) must agree
-        np.testing.assert_array_equal(np.asarray(prewarm),
-                                      np.full(N_LANES, prewarm[0]))
-        np.testing.assert_array_equal(np.asarray(cum),
-                                      np.tile(np.asarray(cum[:1]), (N_LANES, 1)))
-    return out
+    n_ev, n = len(its), LAYOUTS[layout]
+    assert n_ev <= MAX_EVENTS
+    ta, _ = scan_blocks(n, n_ev, CFG.n_bins, TILE_APPS)
+    assert (ta % 1024 == 0) == (layout == "packed")
+    clock = np.cumsum(np.asarray(its, np.float64))
+    times = np.full((n, n_ev), np.inf)
+    lane, event = np.tril_indices(n_ev)
+    times[lane, event] = clock[event]
+    gaps, _ = _gap_columns(times, CFG.bin_minutes, CFG.n_bins)
+    it_lo, it_hi, code = (jnp.asarray(g.T) for g in gaps)
+    (cum, oob, _, _, prewarm, unload_at, cold, waste) = (
+        np.asarray(x[0]) for x in _scan(
+            it_lo, it_hi, code, _init(1, CFG.n_bins, n, CFG_F32), CFG_I32,
+            CFG_F32))
+    prewarm, unload_at = prewarm[0], unload_at[0]
+    oob, cold, waste = oob[0], cold[0], waste[0]
+    # event-free lanes keep the initial state
+    np.testing.assert_array_equal(cum[:, n_ev:], 0)
+    for x in (oob, prewarm, cold, waste):
+        np.testing.assert_array_equal(x[n_ev:], 0)
+    np.testing.assert_array_equal(unload_at[n_ev:], HYB.standard_keep_alive)
+    counts = np.diff(np.concatenate(
+        [np.zeros((1, n), np.int64), cum.astype(np.int64)]), axis=0)
+    # the state holds residency bounds; keep-alive is their exact float64
+    # difference (same reconstruction the drivers use)
+    return [(float(prewarm[i]), float(unload_at[i]) - float(prewarm[i]),
+             int(cum[-1, i]), int(oob[i]), counts[:, i])
+            for i in range(n_ev)]
 
 
 def _scalar_stream(its):
@@ -78,13 +90,13 @@ def _scalar_stream(its):
     return out
 
 
-def _check_stream(its):
+def _check_stream(its, layout):
     """its[0] is the first arrival (not recorded); its[1:] are idle times.
 
-    Times are kept on a dyadic grid well inside float32 range so the kernel
-    recovers every idle time exactly from its carried float32 clock.
+    Times are kept on a dyadic grid so the float64 clock differences back
+    to exactly the idle times the scalar reference is given.
     """
-    got = _kernel_stream(its)
+    got = _kernel_stream(its, layout)
     want = _scalar_stream(its)
     for k, ((gp, gk, gt, go, gc), (wp, wk, wt, wo, wc)) in enumerate(
             zip(got, want)):
@@ -94,6 +106,7 @@ def _check_stream(its):
         # single-source float32 decision layer: windows match bit-for-bit
         assert gp == wp, f"event {k}: prewarm {gp} != {wp}"
         assert gk == wk, f"event {k}: keep {gk} != {wk}"
+    return got
 
 
 def _quantize(vals):
@@ -101,15 +114,20 @@ def _quantize(vals):
     return [max(round(v * 64.0) / 64.0, 0.0) for v in vals]
 
 
+layouts = pytest.mark.parametrize("layout", sorted(LAYOUTS))
+
+
 # --- seeded streams (always run) --------------------------------------------
 
-def test_fused_kernel_parity_in_bounds_stream():
+@layouts
+def test_fused_kernel_parity_in_bounds_stream(layout):
     rng = np.random.default_rng(0)
     its = _quantize(rng.uniform(0.5, 40.0, 60))
-    _check_stream(its)
+    _check_stream(its, layout)
 
 
-def test_fused_kernel_parity_oob_heavy_stream():
+@layouts
+def test_fused_kernel_parity_oob_heavy_stream(layout):
     """Most idle times beyond the histogram range: the representativeness
     check must veto the histogram windows on both paths."""
     rng = np.random.default_rng(1)
@@ -117,33 +135,33 @@ def test_fused_kernel_parity_oob_heavy_stream():
                                 3.0 * CFG.range_minutes, 40))
     its[5] = 3.0   # a couple in-bounds so total > 0
     its[11] = 7.0
-    _check_stream(its)
+    _check_stream(its, layout)
 
 
-def test_fused_kernel_parity_sub_min_samples():
+@layouts
+def test_fused_kernel_parity_sub_min_samples(layout):
     its = _quantize([4.0, 4.0, 4.0])   # fewer than min_samples ITs
-    _check_stream(its)
+    got = _check_stream(its, layout)
     # standard keep-alive must be in force after so few samples
-    got = _kernel_stream(its)
     assert got[-1][0] == 0.0
     assert got[-1][1] == HYB.standard_keep_alive
 
 
-def test_fused_kernel_parity_bimodal_prewarm_stream():
+@layouts
+def test_fused_kernel_parity_bimodal_prewarm_stream(layout):
     """Concentrated bimodal ITs push CV over threshold: histogram windows
     (prewarm > 0) activate and must match the scalar decision."""
-    rng = np.random.default_rng(2)
     its = _quantize([10.0 if i % 2 else 30.0 for i in range(50)])
-    _check_stream(its)
-    got = _kernel_stream(its)
+    got = _check_stream(its, layout)
     assert got[-1][0] > 0.0   # pre-warming active
 
 
-def test_fused_kernel_parity_mixed_random_streams():
+@layouts
+def test_fused_kernel_parity_mixed_random_streams(layout):
     for seed in range(3, 7):
         rng = np.random.default_rng(seed)
         its = _quantize(np.abs(rng.normal(0.0, CFG.range_minutes, 30)))
-        _check_stream(its)
+        _check_stream(its, layout)
 
 
 # --- hypothesis property tests (absent hypothesis, only these skip; the
@@ -161,16 +179,18 @@ if _HAVE_HYPOTHESIS:
                   allow_nan=False),
         min_size=1, max_size=40)
 
+    @layouts
     @settings(max_examples=25, deadline=None)
-    @given(its_strategy)
-    def test_fused_kernel_parity_property(values):
-        _check_stream(_quantize(values))
+    @given(values=its_strategy)
+    def test_fused_kernel_parity_property(layout, values):
+        _check_stream(_quantize(values), layout)
 
+    @layouts
     @settings(max_examples=10, deadline=None)
-    @given(st.lists(st.integers(0, 3 * int(CFG.range_minutes)), min_size=1,
-                    max_size=40))
-    def test_fused_kernel_parity_property_integer(values):
-        _check_stream([float(v) for v in values])
+    @given(values=st.lists(st.integers(0, 3 * int(CFG.range_minutes)),
+                           min_size=1, max_size=40))
+    def test_fused_kernel_parity_property_integer(layout, values):
+        _check_stream([float(v) for v in values], layout)
 else:
     @pytest.mark.skip(reason="hypothesis not installed (requirements-dev.txt)")
     def test_fused_kernel_parity_property():

@@ -123,55 +123,3 @@ def test_rglru_matches_sequential():
         hs = an[:, t] * hs + np.sqrt(1 - an[:, t] ** 2) * bn[:, t]
         want[:, t] = hs
     np.testing.assert_allclose(h, want, atol=1e-5, rtol=1e-4)
-
-
-@pytest.mark.parametrize("napps,nbins,tile", [(64, 48, 32), (128, 240, 64),
-                                              (32, 16, 32)])
-def test_policy_update_kernel(napps, nbins, tile):
-    counts = jnp.asarray(RNG.integers(0, 5, (napps, nbins)), jnp.int32)
-    oob = jnp.asarray(RNG.integers(0, 3, napps), jnp.int32)
-    total = counts.sum(1)
-    cvs = total.astype(jnp.float32)
-    cvss = jnp.asarray((np.asarray(counts) ** 2).sum(1), jnp.float32)
-    bins = jnp.asarray(RNG.integers(0, nbins + 8, napps), jnp.int32)
-    active = jnp.asarray(RNG.integers(0, 2, napps), jnp.int32)
-    kw = dict(range_minutes=float(nbins))
-    outs = ops.policy_update(counts, oob, total, cvs, cvss, bins, active,
-                             tile_apps=tile, **kw)
-    refs = ref.policy_update_ref(counts, oob, total, cvs, cvss, bins, active,
-                                 **kw)
-    for o, r in zip(outs, refs):
-        np.testing.assert_allclose(np.asarray(o, np.float64),
-                                   np.asarray(r, np.float64), atol=1e-5)
-
-
-def test_policy_kernel_matches_core_scalar():
-    """Kernel windows == repro.core.AppHistogram windows on the same stream."""
-    from repro.core.histogram import AppHistogram, HistogramConfig
-    cfg = HistogramConfig(range_minutes=48.0)
-    nbins = cfg.n_bins
-    its = RNG.integers(0, 60, 40)  # some OOB
-    h = AppHistogram(cfg)
-    counts = jnp.zeros((8, nbins), jnp.int32)
-    oob = jnp.zeros((8,), jnp.int32)
-    total = jnp.zeros((8,), jnp.int32)
-    cvs = jnp.zeros((8,), jnp.float32)
-    cvss = jnp.zeros((8,), jnp.float32)
-    prewarm = keep = None
-    for it in its:
-        h.record(float(it))
-        bins = jnp.full((8,), int(it), jnp.int32)
-        active = jnp.ones((8,), jnp.int32)
-        (counts, oob, total, cvs, cvss, prewarm, keep, use_hist) = \
-            ops.policy_update(counts, oob, total, cvs, cvss, bins, active,
-                              range_minutes=cfg.range_minutes, tile_apps=8)
-    pw, ka = h.windows()
-    seen = h.total + h.oob
-    oobf = h.oob_fraction
-    expect_hist = (seen >= 5 and h.cv >= 2.0 and h.total > 0 and oobf <= 0.5)
-    if expect_hist:
-        np.testing.assert_allclose(float(prewarm[0]), pw, atol=1e-4)
-        np.testing.assert_allclose(float(keep[0]), ka, atol=1e-4)
-    else:
-        assert float(prewarm[0]) == 0.0
-        np.testing.assert_allclose(float(keep[0]), cfg.range_minutes)

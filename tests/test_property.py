@@ -86,27 +86,36 @@ def test_hybrid_never_negative_windows(iats):
 @settings(max_examples=25, deadline=None)
 @given(st.integers(1, 64), st.integers(2, 120))
 def test_batched_policy_kernel_invariants(napps, nbins):
-    """Kernel outputs: counts conserved, windows in range, use_hist sane."""
+    """Scan-kernel final state over random gap columns: every closed gap
+    counted once (in bounds or OOB), the cumulative counts' last bin is the
+    in-bounds total, windows non-negative and within range x (1 + margin)."""
     import jax.numpy as jnp
-    from repro.kernels import ops
+    from repro.core.simulator import _build_pallas_cfg, _gap_columns
+    from repro.kernels.histogram import hybrid_sweep_scan_pallas
+    from test_sweep_scan_kernel import _init
     rng = np.random.default_rng(napps * 1000 + nbins)
-    counts = jnp.asarray(rng.integers(0, 4, (napps, nbins)), jnp.int32)
-    total = counts.sum(1)
-    oob = jnp.asarray(rng.integers(0, 2, napps), jnp.int32)
-    cvs = total.astype(jnp.float32)
-    cvss = jnp.asarray((np.asarray(counts) ** 2).sum(1), jnp.float32)
-    bins = jnp.asarray(rng.integers(0, nbins + 4, napps), jnp.int32)
-    active = jnp.asarray(rng.integers(0, 2, napps), jnp.int32)
-    (nc, no, nt, _, _, pw, ka, uh) = ops.policy_update(
-        counts, oob, total, cvs, cvss, bins, active,
-        range_minutes=float(nbins), tile_apps=min(napps, 32))
-    in_b = (np.asarray(active) != 0) & (np.asarray(bins) < nbins)
-    oob_b = (np.asarray(active) != 0) & (np.asarray(bins) >= nbins)
-    np.testing.assert_array_equal(np.asarray(nt),
-                                  np.asarray(total) + in_b)
-    np.testing.assert_array_equal(np.asarray(no), np.asarray(oob) + oob_b)
-    assert np.all(np.asarray(nc).sum(1) == np.asarray(nt))
-    assert np.all(np.asarray(pw) >= 0)
-    assert np.all(np.asarray(ka) >= 0)
-    assert np.all(np.asarray(pw) + np.asarray(ka)
-                  <= float(nbins) * 1.1 + 1e-4)
+    width = 12
+    cfg = HybridConfig(histogram=HistogramConfig(range_minutes=float(nbins)),
+                       use_arima=False)
+    n_ev = rng.integers(0, width + 1, napps)
+    gaps = rng.uniform(0.0, 1.5 * nbins, (napps, width))
+    times = np.cumsum(gaps, axis=1)
+    times[np.arange(width)[None, :] >= n_ev[:, None]] = np.inf
+    cols, _ = _gap_columns(times, 1.0, nbins)
+    ci, cf = _build_pallas_cfg([cfg])
+    (cum, oob, _, _, pw, ub, cold, _) = (
+        np.asarray(x[0]) for x in hybrid_sweep_scan_pallas(
+            *(jnp.asarray(c.T) for c in cols), _init(1, nbins, napps, cf),
+            ci, cf, tile_apps=1024, interpret=True))
+    code = cols[2]
+    in_b = ((code >= 0) & (code < nbins)).sum(axis=1)
+    closed = np.maximum(n_ev - 1, 0)
+    np.testing.assert_array_equal(cum[-1], in_b)
+    np.testing.assert_array_equal(cum[-1] + oob[0], closed)
+    assert np.all(np.diff(cum, axis=0) >= 0)
+    np.testing.assert_array_equal(cold[0] > 0, n_ev > 0)
+    assert np.all(cold[0] <= n_ev)
+    ka = ub[0].astype(np.float64) - pw[0]
+    assert np.all(pw[0] >= 0)
+    assert np.all(ka >= 0)
+    assert np.all(ub[0] <= float(nbins) * (1 + cfg.histogram.margin) + 1e-4)

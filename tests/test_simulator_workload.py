@@ -325,7 +325,7 @@ def test_zero_event_apps_consistent_across_engines():
     for trace, zsel in ((t, zeros), (lt, np.asarray([1]))):
         spec = HybridSpec(range_minutes=48.0, use_arima=False)
         results = {eng: run(trace, spec, engine=eng)
-                   for eng in ("scalar", "fused", "pallas", "reference")}
+                   for eng in ("scalar", "fused", "pallas")}
         base = results["scalar"]
         assert np.all(base.invocations[zsel] == 0)
         assert np.all(base.cold[zsel] == 0)
