@@ -29,18 +29,22 @@ columnar :class:`repro.serving.apptable.AppTable` in three array passes:
   D. **HBM evictions to a fixed point.** Workers whose assigned image
      bytes exceed the budget (a cheap pessimistic screen — everyone else
      skips this phase entirely) replay their per-worker occupancy in the
-     oracle's processing order: one op list (expiries, pre-warm fires,
-     request loads, end-of-request unloads, phase-ordered exactly as
-     ``WarmPool.tick``/``on_request`` interleave them) whose running
-     cumsum exposes every over-budget load. Each violation is resolved the
-     way ``WarmPool._ensure_budget`` would — evict resident, unpinned apps
-     in ``(unload_at, app_id)`` order until the load fits — then the
+     oracle's processing order (expiries, pre-warm fires, request loads,
+     end-of-request unloads, phase-ordered exactly as
+     ``WarmPool.tick``/``on_request`` interleave them). One int64 table
+     for the call holds every risky worker's running image bytes at two
+     positions a tick — after its expiries and fires, after its request's
+     load — and exposes every over-budget load. Each violation is resolved
+     the way ``WarmPool._ensure_budget`` would — evict resident, unpinned
+     apps in ``(unload_at, app_id)`` order until the load fits — then the
      occupancy is patched *in place* (an eviction only removes residency
      between the eviction and the victim's next arrival, which flips cold)
-     and the scan resumes. Because an eviction never adds occupancy before
-     the violation that caused it, the scan position is monotone and the
-     schedule converges to the oracle's in at most one resolution per
-     ``_ensure_budget`` call that evicts.
+     and the walk goes on to the next over-budget position. Because an
+     eviction never adds occupancy before the violation that caused it,
+     the walk is monotone and the schedule converges to the oracle's in at
+     most one resolution per ``_ensure_budget`` call that evicts; its work
+     follows the violations and the images resident at each, not the
+     worker's events.
 
 Spans and counters (``repro.telemetry``; spans are recorded only under a
 profiler, on the calling thread): ``cluster.merge`` (phase A and the
@@ -52,8 +56,9 @@ for the scan, the copy back and the scatter into the window arrays);
 every worker's fixed point) and ``cluster.results`` (loads and unloads,
 latencies, per-worker stats). Counters, per call: ``cluster.events``
 (events replayed), ``cluster.evictions``, ``cluster.evict_rounds``
-(fixed-point resolutions) and ``cluster.risky_workers`` (workers past the
-phase-D screen).
+(fixed-point resolutions), ``cluster.evict_candidates`` (candidate images
+examined over those resolutions) and ``cluster.risky_workers`` (workers
+past the phase-D screen).
 
 Exactness contract (enforced by ``tests/test_cluster_conformance.py``):
 cold counts, per-app cold %, latencies and every
@@ -69,6 +74,7 @@ single image larger than the per-worker budget outright.
 from __future__ import annotations
 
 import dataclasses
+import heapq
 import warnings
 from functools import partial
 from typing import List, Optional, Sequence, Union
@@ -394,138 +400,215 @@ def _app_tie_ranks(table: AppTable) -> np.ndarray:
     return ranks
 
 
-def _evict_worker(j_idx, budget, *, rows, rank, t_by_rank, wb, tie, cold,
-                  stay, pre, fired, need_u, need_f, ui_stay, ui_fire,
-                  tau_i, u_stay, q_fire, p_pre, max_rounds):
+def _eviction_tables(risky, woff, tick_src, budget, *, rows, t_flat, wb,
+                     tie, cold, stay, fired, pre, need_u, need_f, ui_stay,
+                     ui_fire, tau_i, u_stay, q_fire, p_pre):
+    """One occupancy table for every risky worker of a call (phase D's
+    set-up). Yields ``(j_idx, kw)`` per risky worker, in ``risky`` order:
+    ``j_idx`` its flat events in rank order (phase C's ``tick_src``) and
+    ``kw`` the keyword arrays :func:`_evict_worker` takes.
+
+    The oracle's processing order puts every memory op at one of the
+    worker's event ranks (its *tick*): keep-alive expiries and pre-warm
+    fires at the tick that triggers them, a request's load and its
+    pre-warm branch's end-of-request unload at its own. Within a tick,
+    ``WarmPool.tick`` / ``on_request`` run expiries, then fires by
+    ``(prewarm_at, app_id)``, then the request, then the unload. Only
+    fires and cold loads are checked against the budget, so the table
+    holds two positions a tick: ``2 t`` after the tick's expiries and
+    fires, ``2 t + 1`` after its request's load. ``occ`` there is the
+    running int64 image bytes of the worker (per-tick sums of whole bytes,
+    so exact in any order).
+
+    A fire block's occupancy only grows from fire to fire, so a tick's
+    fires can only go over budget if the whole block does; only those
+    ticks' fires are sorted by ``(prewarm_at, app_id)`` (``blocks``, one
+    group per over-budget fire block, in position order).
+    """
+    segs = [(int(woff[w]), int(woff[w + 1])) for w in risky]
+    ticks = np.concatenate([tick_src[a:b] for a, b in segs])
+    m = ticks.size
+    toff = np.zeros(len(segs) + 1, np.int64)
+    np.cumsum([b - a for a, b in segs], out=toff[1:])
+    on = np.zeros(rows.size, bool)
+    on[ticks] = True
+    tpos = np.empty(rows.size, np.int64)      # flat event -> its tick
+    tpos[ticks] = np.arange(m)
+
+    ui = np.where(stay, ui_stay, ui_fire)
+    sched = need_u | need_f                   # a scheduled unload
+    g_exp = np.flatnonzero(on & sched & (ui >= 0))
+    tk_exp = tpos[ui[g_exp]]
+    g_fire = np.flatnonzero(on & fired)
+    tk_fire = tpos[tau_i[g_fire]]
+    n_fire = np.bincount(tk_fire, minlength=m)
+    app_t = rows[ticks]
+    w_t = wb[app_t]
+    cold_t = cold[ticks]
+    unload = np.where(pre[ticks], w_t, 0)
+    unload[toff[1:] - 1] = 0                  # never across workers
+    # Whole bytes: the float64 per-tick sums are exact.
+    wf = wb.astype(np.float64)
+    delta = np.empty((m, 2), np.int64)
+    delta[:, 0] = (np.bincount(tk_fire, weights=wf[rows[g_fire]],
+                               minlength=m)
+                   - np.bincount(tk_exp, weights=wf[rows[g_exp]],
+                                 minlength=m))
+    delta[1:, 0] -= unload[:-1]               # the previous request's
+    delta[:, 1] = np.where(cold_t, w_t, 0)
+    delta = delta.ravel()
+    first = 2 * toff
+    delta[first[1:-1]] -= np.add.reduceat(delta, first[:-1])[:-1]
+    occ = np.cumsum(delta)
+
+    # Over-budget positions as the table stands: fire blocks, cold loads.
+    cap = int(np.floor(budget))
+    occ2 = occ.reshape(m, 2)
+    f_on = (n_fire > 0) & (occ2[:, 0] > cap)
+    viol = np.flatnonzero(np.column_stack([f_on, cold_t
+                                           & (occ2[:, 1] > cap)]))
+    on_fire = f_on[tk_fire]
+    blocks = g_fire[on_fire]
+    o = np.lexsort((tie[rows[blocks]], p_pre[blocks], tk_fire[on_fire]))
+    blocks = blocks[o]
+    boff = np.zeros(int(f_on.sum()) + 1, np.int64)
+    np.cumsum(n_fire[f_on], out=boff[1:])
+
+    # Resident spans, one per active gap, in table positions: from the
+    # load (its own request, or its fire) to the scheduled expiry (before
+    # that tick's fires), the next arrival (a warm continuation) or the
+    # worker's end; ``-1`` for a gap that holds nothing.
+    start = 2 * tpos + 1
+    start[g_fire] = 2 * tk_fire
+    active = on & (stay | fired)
+    end = np.empty(rows.size, np.int64)
+    end[ticks] = np.repeat(first[1:], np.diff(toff))
+    end[~active] = -1
+    end[g_exp] = 2 * tk_exp
+    warm = active & ~sched
+    end[warm] = 2 * tpos[np.flatnonzero(warm) + 1] + 1
+    u_time = np.where(stay, u_stay, q_fire)
+
+    vb = np.searchsorted(viol, first)
+    bb = np.searchsorted(np.flatnonzero(f_on), toff)
+    for i in range(len(segs)):
+        yield ticks[toff[i]:toff[i + 1]], dict(
+            t0=int(toff[i]), occ=occ, viol=viol[vb[i]:vb[i + 1]],
+            blocks=blocks, block_at=boff[bb[i]:bb[i + 1] + 1], tpos=tpos,
+            start=start, end=end, u_time=u_time, warm=warm, rows=rows,
+            t_flat=t_flat, wb=wb, tie=tie, cold=cold)
+
+
+def _evict_worker(j_idx, budget, *, t0, occ, viol, blocks, block_at,
+                  tpos, start, end, u_time, warm, rows, t_flat, wb, tie,
+                  cold, max_rounds):
     """Exact HBM-eviction replay for one worker (phase D).
 
-    ``j_idx`` holds the worker's flat event indices in ``(app, k)`` order;
-    every other array is global flat-event state from the gap replay. The
-    worker's memory ops are laid out in the oracle's processing order —
-    per event rank, keep-alive expiries (phase 0), then pre-warm fires
-    ordered by ``(prewarm_at, app_id)`` (phase 1), then the request load
-    (phase 2), then the end-of-request unload (phase 3) — and the running
-    occupancy cumsum is scanned for over-budget loads. Each violation is
-    resolved like ``WarmPool._ensure_budget``: resident spans covering the
-    violation are candidates, evicted in ``(unload_at, app_id)`` order
-    until the load fits (or counted as a budget overflow when nothing
-    evictable remains). An eviction removes the victim's occupancy only
-    between the violation and the victim's next scheduled end — its next
-    arrival (flipped to a cold load, in-place in ``cold``) or scheduled
-    expiry — so the patch is a slice subtraction and the scan resumes
-    forward; positions are monotone, so each ``_ensure_budget`` call is
-    resolved exactly once.
+    ``j_idx`` holds the worker's flat event indices in rank order, its
+    ticks ``t0 + 0, 1, ...`` of the call's table ``occ``
+    (:func:`_eviction_tables`); ``viol`` are its over-budget positions
+    there and ``blocks[block_at[b]:block_at[b + 1]]`` the fires of its
+    ``b``-th over-budget fire block. Each over-budget load (a fire, in
+    ``(prewarm_at, app_id)`` order within its block, or a cold request)
+    is resolved like ``WarmPool._ensure_budget``:
+    resident spans covering it are candidates, evicted in ``(unload_at,
+    app_id)`` order until the load fits (or counted as a budget overflow
+    when nothing evictable remains). An eviction removes the victim's
+    occupancy only between the load and the victim's span end — its next
+    arrival (flipped to a cold load, in place in ``cold``) or scheduled
+    expiry — so the patch is a slice subtraction.
+
+    Occupancy only falls, and a request only turns into a check at a
+    victim's next arrival, after the load: so the over-budget positions
+    are walked once, with a heap of the requests turned cold, each
+    re-tested when reached. The candidates are kept as the walk advances:
+    each tick's gap joins a pool once the walk passes its request, and
+    leaves it once its span ended or was evicted; the pool holds about one
+    gap per app of the worker, not the worker's events.
 
     Returns ``(evicted_local, evict_time_local, overflows, rounds)``.
     """
-    E = len(j_idx)
-    app = rows[j_idx]
-    w_b = wb[j_idx].astype(np.float64)
-    g_tie = tie[app]
-    step = rank[j_idx]
-    st_g, pre_g, fired_g = stay[j_idx], pre[j_idx], fired[j_idx]
-    nu_g, nf_g = need_u[j_idx], need_f[j_idx]
+    cap = int(np.floor(budget))      # whole bytes: occ > budget iff > cap
+    rounds = overflows = examined = 0
+    victims, times = [], []
+    walk = viol.tolist()
+    turned = []                      # requests turned cold: a heap
+    pool = np.zeros(0, np.int64)
+    pooled = 0                       # local ticks whose gaps joined
+    n_block = 0
+    i = 0
 
-    # ---- op table (unsorted layout: expiries | fires | slots | ends) ----
-    ui_g = np.where(st_g, ui_stay[j_idx], ui_fire[j_idx])
-    g_exp = np.nonzero((nu_g | nf_g) & (ui_g >= 0))[0]
-    g_fire = np.nonzero(fired_g)[0]
-    g_end = np.nonzero(pre_g)[0]
-    n_exp, n_fire, n_end = len(g_exp), len(g_fire), len(g_end)
-    slot0 = n_exp + n_fire
-    N = slot0 + E + n_end
-
-    op_gap = np.concatenate([g_exp, g_fire, np.arange(E), g_end])
-    op_step = np.concatenate([rank[ui_g[g_exp]], rank[tau_i[j_idx[g_fire]]],
-                              step, step[g_end]])
-    op_phase = np.concatenate([np.zeros(n_exp, np.int8),
-                               np.ones(n_fire, np.int8),
-                               np.full(E, 2, np.int8),
-                               np.full(n_end, 3, np.int8)])
-    op_sub1 = np.zeros(N)
-    op_sub1[n_exp:slot0] = p_pre[j_idx[g_fire]]
-    op_sub2 = np.zeros(N, np.int64)
-    op_sub2[n_exp:slot0] = g_tie[g_fire]
-    op_delta = np.concatenate([-w_b[g_exp], w_b[g_fire],
-                               w_b * cold[j_idx], -w_b[g_end]])
-    op_need = np.concatenate([np.zeros(n_exp), w_b[g_fire], w_b,
-                              np.zeros(n_end)])
-    op_check = np.concatenate([np.zeros(n_exp, bool), np.ones(n_fire, bool),
-                               cold[j_idx].copy(), np.zeros(n_end, bool)])
-
-    srt = np.lexsort((op_sub2, op_sub1, op_phase, op_step))
-    pos_of = np.empty(N, np.int64)
-    pos_of[srt] = np.arange(N)
-    slot_pos = pos_of[slot0:slot0 + E]
-    fire_pos = np.full(E, -1, np.int64)
-    fire_pos[g_fire] = pos_of[n_exp:slot0]
-    exp_pos = np.full(E, -1, np.int64)
-    exp_pos[g_exp] = pos_of[:n_exp]
-
-    occ = np.cumsum(op_delta[srt])
-    check_s = op_check[srt]
-    need_s = op_need[srt]
-    gap_s = op_gap[srt]
-    step_s = op_step[srt]
-
-    # ---- resident spans per gap, in op positions -----------------------
-    active = st_g | fired_g
-    span_start = np.where(st_g, slot_pos, fire_pos)
-    has_sched = np.where(st_g, nu_g, nf_g)
-    span_end = np.full(E, N, np.int64)          # scheduled end at run end
-    found = active & has_sched & (exp_pos >= 0)
-    span_end[found] = exp_pos[found]
-    warm_cont = active & ~has_sched             # continues into next event
-    if warm_cont.any():
-        g_nxt = np.searchsorted(j_idx, j_idx[warm_cont] + 1)
-        span_end[warm_cont] = slot_pos[g_nxt]
-    u_time = np.where(st_g, u_stay[j_idx], q_fire[j_idx])
-
-    # ---- scan + resolve ------------------------------------------------
-    evicted = np.zeros(E, bool)
-    evict_t = np.zeros(E)
-    overflows = 0
-    rounds = 0
-    s = 0
-    while s < N:
-        seg = check_s[s:] & (occ[s:] > budget)
-        rel = int(np.argmax(seg))
-        if not seg[rel]:
-            break
-        v = s + rel
+    def resolve(cand, excess, v, t_v):
+        """Evict ``cand``'s soonest expiries until ``excess`` bytes are
+        freed; the bytes freed."""
+        nonlocal rounds, overflows, examined
         rounds += 1
         if rounds > max_rounds:
             raise EvictionRoundsExceeded(
                 f"eviction fixed point exceeded max_eviction_rounds="
                 f"{max_rounds} on one worker")
-        a_v = app[gap_s[v]]
-        t_v = t_by_rank[step_s[v]]
-        need = need_s[v]
-        used_before = occ[v] - need
-        cand = np.nonzero(active & ~evicted & (span_start < v)
-                          & (span_end > v) & (app != a_v))[0]
-        if len(cand):
-            cand = cand[np.lexsort((g_tie[cand], u_time[cand]))]
-            freed = np.cumsum(w_b[cand])
-            k = int(np.searchsorted(freed, used_before + need - budget,
-                                    side="left")) + 1
-            if k > len(cand):
-                k = len(cand)
-                overflows += 1
-            victims = cand[:k]
-        else:
-            victims = cand
+        examined += len(cand)
+        a_c = rows[cand]
+        w_c = wb[a_c]
+        o = np.lexsort((tie[a_c], u_time[cand]))
+        k = int(w_c[o].cumsum().searchsorted(excess)) + 1
+        if k > len(cand):
+            k = len(cand)
             overflows += 1
-        for g_e in victims:
-            evicted[g_e] = True
-            evict_t[g_e] = t_v
-            occ[v:span_end[g_e]] -= w_b[g_e]
-            if warm_cont[g_e]:
+        freed = 0
+        for g, w_g in zip(cand[o[:k]].tolist(), w_c[o[:k]].tolist()):
+            e = int(end[g])
+            occ[v:e] -= w_g
+            end[g] = -1              # gone: never a candidate again
+            freed += w_g
+            victims.append(g)
+            times.append(t_v)
+            if warm[g]:
                 # The victim's next arrival finds the image gone: cold.
-                j_n = j_idx[g_e] + 1
-                cold[j_n] = True
-                check_s[slot_pos[np.searchsorted(j_idx, j_n)]] = True
-        s = v + 1
+                cold[g + 1] = True
+                heapq.heappush(turned, e)
+        return freed
+
+    while True:
+        if turned and (i == len(walk) or turned[0] < walk[i]):
+            v = heapq.heappop(turned)
+        elif i < len(walk):
+            v = walk[i]
+            i += 1
+        else:
+            break
+        t = v // 2 - t0
+        if not v % 2:
+            block = blocks[block_at[n_block]:block_at[n_block + 1]]
+            n_block += 1
+        if int(occ[v]) <= cap:
+            continue
+        if t > pooled:
+            pool = np.concatenate([pool, j_idx[pooled:t]])
+            pooled = t
+        pool = pool[end[pool] > v]
+        older = pool[start[pool] < v]
+        t_v = float(t_flat[j_idx[t]])
+        if v % 2:                    # a cold request's load
+            a_v = rows[j_idx[t]]
+            resolve(older[rows[older] != a_v], int(occ[v]) - cap, v, t_v)
+            continue
+        # A fire block: its fires in order, each on the bytes before it.
+        w_b = wb[rows[block]]
+        used = int(occ[v]) - int(w_b.sum())
+        for j, (g, w_g) in enumerate(zip(block.tolist(), w_b.tolist())):
+            used += w_g
+            if used <= cap:
+                continue
+            cand = np.concatenate([older, block[:j]])
+            cand = cand[end[cand] > v]       # not evicted since
+            used -= resolve(cand[rows[cand] != rows[g]], used - cap, v, t_v)
+    telemetry.count("cluster.evict_candidates", examined)
+    evicted = np.zeros(len(j_idx), bool)
+    evict_t = np.zeros(len(j_idx))
+    local = tpos[np.asarray(victims, np.int64)] - t0
+    evicted[local] = True
+    evict_t[local] = times
     return evicted, evict_t, overflows, rounds
 
 
@@ -636,7 +719,6 @@ def _run_vector(table: AppTable, spec: PolicySpec, cluster: ClusterSpec,
         # their exact processing-order occupancy (and most find no
         # violation).
         wb = table.weight_bytes.astype(np.float64)
-        wb_flat = wb[rows]
         evicted = np.zeros(n_events, bool)
         evict_time = np.zeros(n_events)
         overflow_w = np.zeros(n_workers, np.int64)
@@ -647,19 +729,18 @@ def _run_vector(table: AppTable, spec: PolicySpec, cluster: ClusterSpec,
                                          minlength=n_workers)
             risky = np.nonzero(per_w_assigned > budget)[0]
             if len(risky):
-                tie = _app_tie_ranks(table)
-                t_by_rank = t_flat[order]
                 max_rounds = (max_eviction_rounds if max_eviction_rounds
                               is not None else np.inf)
-                for w in risky:
-                    j_w = np.nonzero(w_flat == w)[0]
+                tables = _eviction_tables(
+                    risky, woff, tick_src, budget, rows=rows, t_flat=t_flat,
+                    wb=np.asarray(table.weight_bytes, np.int64),
+                    tie=_app_tie_ranks(table), cold=cold, stay=stay,
+                    fired=fired, pre=pre, need_u=need_u, need_f=need_f,
+                    ui_stay=ui_stay, ui_fire=ui_fire, tau_i=tau_i,
+                    u_stay=u_stay, q_fire=q_fire, p_pre=p_pre)
+                for w, (j_w, kw) in zip(risky, tables):
                     ev_l, evt_l, n_over, used = _evict_worker(
-                        j_w, budget, rows=rows, rank=rank,
-                        t_by_rank=t_by_rank, wb=wb_flat, tie=tie, cold=cold,
-                        stay=stay, pre=pre, fired=fired, need_u=need_u,
-                        need_f=need_f, ui_stay=ui_stay, ui_fire=ui_fire,
-                        tau_i=tau_i, u_stay=u_stay, q_fire=q_fire,
-                        p_pre=p_pre, max_rounds=max_rounds - rounds)
+                        j_w, budget, max_rounds=max_rounds - rounds, **kw)
                     evicted[j_w] = ev_l
                     evict_time[j_w] = evt_l
                     overflow_w[w] = n_over

@@ -24,7 +24,8 @@ The spans and counters of one invoker-cluster replay
 ``cluster.put`` and ``cluster.pull`` (phase B, per chunk),
 ``cluster.replay`` (phase C), ``cluster.evict`` (phase D) and
 ``cluster.results``; counters ``cluster.events``, ``cluster.evictions``,
-``cluster.evict_rounds`` and ``cluster.risky_workers``.
+``cluster.evict_rounds``, ``cluster.evict_candidates`` and
+``cluster.risky_workers``.
 """
 from __future__ import annotations
 

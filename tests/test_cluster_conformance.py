@@ -323,6 +323,106 @@ def test_eviction_screen_passes_on_interleaved_residency():
     _conform(table, FixedSpec(keep_alive=0.5), cluster)
 
 
+def _gigabyte_table(times, gigabytes, duration):
+    """One app per list of arrival minutes, app ``i``'s image
+    ``gigabytes[i]`` GB, one-second requests."""
+    return AppTable.from_trace(
+        _two_app_trace(times, duration=duration), exec_s=1.0,
+        memory_mb=512.0,
+        weight_bytes=(np.asarray(gigabytes) * 1e9).astype(np.int64))
+
+
+_EVERY_30 = np.arange(10) * 30.0
+
+
+def _eviction_path_case(path, flash_table):
+    """(table, policy, cluster, check) for one path of the fixed point;
+    ``check(result, calls, counts)`` asserts the path ran, from the
+    replay's result, each worker replay's keywords and result, and the
+    call's counters."""
+    one = ClusterSpec(n_workers=1, hbm_budget_bytes=16e9)
+    if path == "fires_on_one_tick":
+        # Apps 0 and 1 share a schedule (equal prewarm_at: the app id
+        # orders them), app 2 runs 3 s behind; all three pre-warms come
+        # due before app 3's arrival, the tick that fires them, and only
+        # two 6 GB images fit.
+        table = _gigabyte_table([_EVERY_30, _EVERY_30, _EVERY_30 + 0.05,
+                                 _EVERY_30 + 29.0], [6, 6, 6, 1], 330.0)
+
+        def check(res, calls, counts):
+            assert sum(s["prewarms"] for s in res.stats_per_worker) > 0
+            assert any(np.diff(kw["block_at"]).max(initial=0) >= 3
+                       for kw, _ in calls)
+            assert res.evictions > 0
+        return table, HybridSpec(use_arima=False), one, check
+    if path == "victim_turns_cold_then_evicts":
+        # B's load evicts A, whose warm next arrival turns cold and, over
+        # budget itself, evicts B: a check the table did not start with.
+        table = _gigabyte_table([[0.0, 5.0], [1.0]], [10, 10], 30.0)
+
+        def check(res, calls, counts):
+            (kw, out), = calls
+            assert out[3] == 2 > len(kw["viol"])
+            assert res.cold_pct_per_app[0] == 100.0
+        return table, FixedSpec(keep_alive=10.0), one, check
+    if path == "every_candidate_evicted":
+        # The 15 GB load fits only once both resident 6 GB images go.
+        table = _gigabyte_table([[0.0], [1.0], [2.0]], [6, 6, 15], 30.0)
+
+        def check(res, calls, counts):
+            assert counts["cluster.evict_rounds"] == 1
+            assert (counts["cluster.evict_candidates"] == res.evictions
+                    == 2)
+        return table, FixedSpec(keep_alive=10.0), one, check
+    if path == "span_to_run_end":
+        # No unload: app 0's only gap is resident to the run's end (no
+        # expiry, no next arrival) until app 2's load evicts it, the
+        # soonest-expiry tie (all at inf) broken by the app id.
+        table = _gigabyte_table([[0.0], [1.0], [2.0]], [6, 6, 10], 30.0)
+
+        def check(res, calls, counts):
+            assert res.evictions == 1
+            assert res.stats_per_worker[0]["unloads"] == 3
+        return table, NoUnloadSpec(), one, check
+    budget = {"storm_20gb": 20e9, "storm_45gb": 45e9}[path]
+
+    def check(res, calls, counts):
+        assert res.evictions > 100
+    return (_oversubscribe(flash_table, budget=budget), HybridSpec(),
+            ClusterSpec(n_workers=3, hbm_budget_bytes=budget), check)
+
+
+@pytest.mark.parametrize("path", [
+    "fires_on_one_tick", "victim_turns_cold_then_evicts",
+    "every_candidate_evicted", "span_to_run_end", "storm_20gb",
+    "storm_45gb"])
+def test_eviction_fixed_point_paths(flash_table, monkeypatch, path):
+    """Each case drives one path of the worker replay against the oracle:
+    a fire block resolved fire by fire in ``(prewarm_at, app_id)`` order,
+    a load turned cold by an eviction that then evicts, a load that takes
+    every candidate, a victim resident to the run's end, and the
+    flash-crowd storm at two more budgets. (An over-budget load with no
+    candidate at all cannot arise in a cluster replay: no app is pinned
+    while another loads, and no image exceeds the budget.)"""
+    from repro import telemetry
+    from repro.serving import cluster_vector
+
+    table, policy, cluster, check = _eviction_path_case(path, flash_table)
+    calls = []
+    replay = cluster_vector._evict_worker
+
+    def recorded(j_idx, budget, **kw):
+        out = replay(j_idx, budget, **kw)
+        calls.append((kw, out))
+        return out
+    monkeypatch.setattr(cluster_vector, "_evict_worker", recorded)
+    before = telemetry.counters()
+    res = _conform(table, policy, cluster)
+    after = telemetry.counters()
+    check(res, calls, {k: after.get(k, 0) - before.get(k, 0)
+                       for k in after})
+
+
 # --------------------------------------------------------------------------
 # AppTable bridges and workload coercion
 # --------------------------------------------------------------------------

@@ -5,8 +5,8 @@ per phase (``cluster.merge``, ``cluster.replay``, ``cluster.evict``,
 ``cluster.results``) and four per policy-window chunk
 (``cluster.bucket``, ``cluster.gap_columns``, ``cluster.put``,
 ``cluster.pull``) on the calling thread; with or without a profiler it
-counts events, evictions, fixed-point rounds and screened workers.
-Neither changes a result.
+counts events, evictions, fixed-point rounds, screened workers and the
+candidate images the fixed point examined. Neither changes a result.
 """
 import glob
 import os
@@ -18,13 +18,13 @@ import jax
 from repro import telemetry
 from repro.serving.cluster_vector import run_cluster
 
-from golden_traces import cluster_oversubscribed_fleet
+from golden_traces import cluster_oversubscribed_fleet, cluster_small_fleet
 
 LEAVES = {"cluster.merge", "cluster.bucket", "cluster.gap_columns",
           "cluster.put", "cluster.pull", "cluster.replay", "cluster.evict",
           "cluster.results"}
 COUNTERS = ("cluster.events", "cluster.evictions", "cluster.evict_rounds",
-            "cluster.risky_workers")
+            "cluster.risky_workers", "cluster.evict_candidates")
 
 
 def _profiled(tmp_path, fn):
@@ -94,6 +94,31 @@ def test_cluster_counters_count_without_a_profiler():
     delta = {k: after.get(k, 0) - before.get(k, 0) for k in COUNTERS}
     assert delta["cluster.events"] == res.latencies_s.size == table.n_events
     assert delta["cluster.evictions"] == res.evictions
+
+
+def test_evict_candidates_counts_the_examined_images():
+    """Candidates examined over every fixed-point round: on the evicting
+    golden fleet the 1,705 images its 414 rounds' candidate sets hold
+    (with its 458 evictions and the oracle's results), none on an
+    eviction-free fleet."""
+    def delta(fleet):
+        table, policy, cluster = fleet()
+        before = telemetry.counters()
+        res = run_cluster(table, policy, cluster, engine="vector")
+        after = telemetry.counters()
+        return res, {k: after.get(k, 0) - before.get(k, 0) for k in COUNTERS}
+
+    res, got = delta(cluster_oversubscribed_fleet)
+    table, policy, cluster = cluster_oversubscribed_fleet()
+    scalar = run_cluster(table, policy, cluster, engine="scalar")
+    np.testing.assert_array_equal(res.latencies_s, scalar.latencies_s)
+    assert res.evictions == scalar.evictions
+    assert got["cluster.evict_rounds"] == 414
+    assert got["cluster.evictions"] == 458
+    assert got["cluster.evict_candidates"] == 1705
+    res, got = delta(cluster_small_fleet)
+    assert res.evictions == 0
+    assert got["cluster.evict_candidates"] == 0
 
 
 def test_a_new_margin_reuses_the_compiled_windows_scan():
